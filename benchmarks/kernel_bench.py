@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit
+from repro.compile_cache import use_compile_cache
 from repro.data.synthetic import make_dataset
 from repro.kernels.goldfinger_knn import ops as gk_ops
 from repro.kernels.goldfinger_knn import ref as gk_ref
@@ -93,6 +94,7 @@ def run_descent(scale: float = 0.1, n_queries: int = 128,
     from repro.core.params import params_for
     from repro.kernels.descent_score import ops as ds_ops
     from repro.kernels.descent_score import ref as ds_ref
+    from repro.kernels.descent_score.descent_score import dma_row_words
     from repro.query.index import build_index
     from repro.query.router import routed_queries
     from repro.query.search import descent_init
@@ -131,9 +133,9 @@ def run_descent(scale: float = 0.1, n_queries: int = 128,
         np.testing.assert_array_equal(np.asarray(di), np.asarray(ri))
         np.testing.assert_array_equal(np.asarray(dsim), np.asarray(rs))
         # DMA accounting must agree with the scored-lane counter: the
-        # kernel fetches exactly the surviving lanes' fingerprint rows.
+        # kernel fetches exactly the surviving lanes' packed rows.
         np.testing.assert_array_equal(np.asarray(dmab),
-                                      np.asarray(dnsc) * W * 4)
+                                      np.asarray(dnsc) * 4 * dma_row_words(W))
         total = beam * (kg + kr)
         scored = float(np.asarray(nsc).mean())
         q_dma = float(np.asarray(dmab).mean())
@@ -168,6 +170,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="small CI run; exit 1 on fused-hop drift")
     args = ap.parse_args()
+    use_compile_cache()
     if args.smoke:
         from repro.kernels.descent_score import tune
 

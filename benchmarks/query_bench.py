@@ -71,6 +71,7 @@ if _n_dev and _n_dev > 1:
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.params import params_for
 from repro.data.synthetic import make_dataset
 from repro.query.engine import QueryConfig, QueryEngine, QueryRequest
@@ -1235,6 +1236,7 @@ def main():
                     help="small CI run; exit 1 on sharded regression")
     ap.add_argument("--out", default="BENCH_query.json")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.smoke:
         args.scale, args.queries = min(args.scale, 0.1), min(args.queries, 64)

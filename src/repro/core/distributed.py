@@ -91,7 +91,6 @@ def distributed_local_knn(plan: ClusterPlan, gf: GoldFinger,
 
     Returns (ids, sims) int32/float32 [t, n, k] as local_knn does.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_dev = int(mesh.shape[data_axis])
@@ -116,8 +115,8 @@ def distributed_local_knn(plan: ClusterPlan, gf: GoldFinger,
     out_specs = tuple((P(data_axis, None, None, None),
                        P(data_axis, None, None, None))
                       for _ in dp.groups)
-    results = shard_map(device_fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)(
+    results = jax.shard_map(device_fn, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)(
         *[jnp.asarray(g) for g in dp.groups])
 
     t, n = plan.t, plan.n_users

@@ -1,42 +1,41 @@
-# One switch for every kernel package: interpret vs compiled Pallas.
+# One rule for every kernel package: interpret vs compiled Pallas.
 #
 # All three kernel wrappers (`descent_score.ops`, `goldfinger_knn.ops`,
 # `frh_minhash.ops`) resolve their `interpret=` argument through
-# `interpret_mode()` at trace time, so the whole repo flips between the
-# interpret-mode emulator (bitwise-checked against each package's
-# `ref.py`, runs anywhere including CPU CI) and compiled TPU kernels
-# with a single environment variable:
+# `interpret_mode()` at trace time, and the platform decides:
 #
-#   REPRO_PALLAS_INTERPRET=1   interpret mode (the default — CPU CI)
-#   REPRO_PALLAS_INTERPRET=0   compile for the attached accelerator
+#   CPU backend  → interpret mode (the Pallas emulator, bitwise-checked
+#                  against each package's `ref.py`)
+#   TPU backend  → compiled Mosaic kernels
+#   anything else → an error: the kernels are written for Mosaic, and a
+#                  silent interpret-mode fallback on an accelerator would
+#                  hide the chip behind the emulator.
 #
-# Accepted falsy spellings: 0 / false / no / off (case-insensitive);
-# anything else — including unset — means interpret mode. Tests (and
-# callers that must not depend on ambient env) can pin the mode
-# programmatically with `set_interpret(True/False)`, which overrides the
-# environment until `set_interpret(None)` restores env-driven behavior.
+# Tests can pin the mode with `set_interpret(True/False)`, which
+# overrides the platform rule until `set_interpret(None)` restores it.
 
 from __future__ import annotations
 
-import os
-
-ENV_VAR = "REPRO_PALLAS_INTERPRET"
-_FALSY = frozenset({"0", "false", "no", "off"})
+import jax
 
 _override: bool | None = None
 
 
 def set_interpret(value: bool | None) -> None:
-    """Pin interpret mode (True/False), or None to follow the env var."""
+    """Pin interpret mode (True/False), or None to follow the platform."""
     global _override
     _override = None if value is None else bool(value)
 
 
 def interpret_mode() -> bool:
-    """Resolve the interpret flag: override first, then REPRO_PALLAS_INTERPRET."""
+    """Resolve the interpret flag: override first, then the backend."""
     if _override is not None:
         return _override
-    raw = os.environ.get(ENV_VAR)
-    if raw is None:
+    backend = jax.default_backend()
+    if backend == "cpu":
         return True
-    return raw.strip().lower() not in _FALSY
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run compiled on TPU or interpreted on CPU; the "
+        f"default JAX backend is {backend!r}")

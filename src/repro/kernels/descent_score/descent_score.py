@@ -42,24 +42,27 @@ precondition, which every real beam satisfies by construction (beams are
 A repeated beam id at two different sims would be ranked at its *first*
 lane by the reference's dedup and at its *max* lane here.
 
-Two memory placements share this hop body:
+Two memory placements:
 
-* :func:`hop_pallas` — the PR 4 layout: index arrays ride in whole as
-  VMEM-style operands (index_map pins block 0). Fine while the tables
-  fit VMEM (n·W·4 bytes ≈ 0.2 MB at n=1600, W=32).
-* :func:`hop_pallas_dma` — the memory-hierarchy-aware layout: all five
-  tables (adjacency fwd/rev, fingerprints, cardinalities, tombstones)
-  stay HBM/ANY-memory refs. Candidate *ids* are still gathered in VMEM,
-  but fingerprint/cardinality rows are fetched per score chunk by
-  double-buffered async-copy DMA into scoped VMEM scratch — copy-in of
-  chunk c+1 overlaps scoring of chunk c — and lanes the suppression mask
-  retired never issue a DMA at all, so the scored-lane counter directly
-  measures bytes not moved. The kernel emits per-query ``dma_bytes`` /
-  ``bytes_saved`` outputs (fingerprint bytes; the invariant
-  ``dma_bytes == n_scored·W·4`` is test-enforced).
+* :func:`hop_pallas` — index arrays ride in whole as VMEM operands
+  (index_map pins block 0) and rows are gathered with whole-table
+  takes. Interpret mode only: Mosaic lowers no such gather, and real
+  tables do not fit VMEM — compiled, it raises before tracing.
+* :func:`hop_pallas_dma` — the compiled layout. Steps (a) and (b) run
+  as XLA ops ahead of the kernel (ids only: the ``[q, beam·(kg+kr)]``
+  int32 lane-id array, never the candidate fingerprints), because
+  Mosaic lowers neither arbitrary-row takes nor DMAs of rows narrower
+  than its 128-lane tile. Each index row's fingerprint and cardinality
+  are packed into one 128-lane-aligned uint32 row
+  (:func:`dma_row_words`) that stays in HBM; the kernel reads the
+  surviving lane ids as scalars from SMEM and fetches their rows per
+  score chunk by double-buffered async-copy DMA — copy-in of chunk c+1
+  overlaps scoring of chunk c — so suppressed lanes move no bytes. It
+  emits per-query ``dma_bytes`` / ``bytes_saved`` (packed-row bytes;
+  ``dma_bytes == n_scored·4·dma_row_words(W)`` is test-enforced).
 
 Both are bitwise-identical to each other and to the reference: they
-share the suppression mask, the chunked estimator
+share the suppression rule, the chunked estimator
 (:func:`repro.kernels.scoring.score_gathered_chunk`) and the merge.
 """
 from __future__ import annotations
@@ -71,41 +74,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.descent_score.ref import hop_candidates
 from repro.kernels.scoring import score_gathered_chunk
 from repro.knn.topk import select_topk
 from repro.sketch.goldfinger import unpack_bits_int8
 from repro.types import NEG_INF, PAD_ID
 
-
-def _mask_dead_beam(beam_ids, beam_sims, tomb):
-    """(a0) tombstone masking of the beam itself, mirroring the ref's
-    pre-masking: lanes naming deleted rows drop to PAD/−inf before the
-    gather, so a dead beam entry contributes no candidates this hop."""
-    bq, B = beam_ids.shape
-    b_dead = (beam_ids != PAD_ID) & (jnp.take(
-        tomb, jnp.where(beam_ids == PAD_ID, 0, beam_ids).reshape(-1)
-    ).reshape(bq, B) > 0)
-    return (jnp.where(b_dead, PAD_ID, beam_ids),
-            jnp.where(b_dead, NEG_INF, beam_sims))
+# Default scoped-VMEM limit of the Mosaic compiler on TPU v5e.
+_VMEM_LIMIT_BYTES = 16 * 1024 * 1024
 
 
-def _suppress(cand, beam_ids, tomb):
-    """(a1)+(b) pre-scoring suppression.
-
-    Tombstoned candidates become PAD lanes *upstream* of the `need`
-    mask — stale edges to deleted rows retire exactly like PAD/in-beam
-    lanes (and are excluded from n_scored, which is how tests observe
-    the suppression). `need` then drops PAD lanes and lanes already in
-    the beam (merge would retire them as duplicates of columns 0..B-1 —
-    scoring them first is the waste this kernel removes)."""
-    bq, C = cand.shape
-    c_dead = (cand != PAD_ID) & (jnp.take(
-        tomb, jnp.where(cand == PAD_ID, 0, cand).reshape(-1)
-    ).reshape(bq, C) > 0)
-    cand = jnp.where(c_dead, PAD_ID, cand)
-    need = (cand != PAD_ID) & ~jnp.any(
+def _not_in_beam(cand, beam_ids):
+    """(b) Lanes worth scoring: not PAD and not already in the beam
+    (merge would retire them as duplicates of columns 0..B-1 — scoring
+    them first is the waste the fused hop removes). Tombstoned lanes are
+    PAD by now (``hop_candidates``), so they retire here too and stay
+    out of n_scored, which is how tests observe the suppression."""
+    return (cand != PAD_ID) & ~jnp.any(
         cand[:, :, None] == beam_ids[:, None, :], axis=-1)
-    return cand, need
 
 
 def _merge(beam_ids, beam_sims, cand, cand_sims, out_ids_ref, out_sims_ref):
@@ -124,26 +110,13 @@ def _hop_kernel(graph_ref, rev_ref, words_ref, card_ref, tomb_ref,
                 qw_ref, qc_ref, bi_ref, bs_ref,
                 out_ids_ref, out_sims_ref, nsc_ref,
                 *, chunk: int, mxu: bool):
-    beam_ids = bi_ref[...]                              # [bq, B] i32
-    beam_sims = bs_ref[...]                             # [bq, B] f32
-    bq, B = beam_ids.shape
-    kg = graph_ref.shape[1]
-    kr = rev_ref.shape[1]
-    tomb = tomb_ref[...][:, 0]                          # [n] i32 (0|1)
-
-    beam_ids, beam_sims = _mask_dead_beam(beam_ids, beam_sims, tomb)
-
-    # (a) adjacency gather — candidate *ids* only.
-    flat = jnp.where(beam_ids == PAD_ID, 0, beam_ids).reshape(-1)
-    dead = beam_ids[:, :, None] == PAD_ID               # [bq, B, 1]
-    fwd = jnp.take(graph_ref[...], flat, axis=0).reshape(bq, B, kg)
-    fwd = jnp.where(dead, PAD_ID, fwd).reshape(bq, B * kg)
-    rev = jnp.take(rev_ref[...], flat, axis=0).reshape(bq, B, kr)
-    rev = jnp.where(dead, PAD_ID, rev).reshape(bq, B * kr)
-    cand = jnp.concatenate([fwd, rev], axis=1)          # [bq, C]
-    C = cand.shape[1]
-
-    cand, need = _suppress(cand, beam_ids, tomb)
+    # (a) adjacency gather — candidate *ids* only — with dead beam and
+    # candidate lanes retired, exactly as the reference does it.
+    beam_ids, beam_sims, cand = hop_candidates(
+        graph_ref[...], rev_ref[...], bi_ref[...], bs_ref[...],
+        tomb_ref[...][:, 0] > 0)                        # cand [bq, C]
+    bq, C = cand.shape
+    need = _not_in_beam(cand, beam_ids)
     nsc_ref[...] = jnp.sum(need, axis=1, dtype=jnp.int32).reshape(bq, 1)
 
     # (c) score surviving lanes, in chunks — the gathered fingerprint
@@ -159,7 +132,7 @@ def _hop_kernel(graph_ref, rev_ref, words_ref, card_ref, tomb_ref,
         need_c = need[:, s:s + chunk]
         ch = ids_c.shape[1]
         safe = jnp.where(need_c, ids_c, 0).reshape(-1)
-        cw = jnp.take(words, safe, axis=0)              # [bq·ch, W]
+        cw = jnp.take(words, safe, axis=0).reshape(bq, ch, -1)
         cc = jnp.where(need_c,
                        jnp.take(card, safe, axis=0).reshape(bq, ch),
                        0).astype(jnp.float32)
@@ -192,6 +165,16 @@ def hop_pallas(graph_ids, rev_ids, words, card, tomb, q_words, q_card,
     q, B = beam_ids.shape
     n, W = words.shape
     kg, kr = graph_ids.shape[1], rev_ids.shape[1]
+    if not interpret:
+        table_bytes = sum(n * 4 * -(-w // 128) * 128
+                          for w in (kg, kr, W, 1, 1))
+        raise NotImplementedError(
+            f"hop_pallas runs in interpret mode only: it holds the index "
+            f"tables whole in VMEM ({table_bytes:,} bytes at n={n}, "
+            f"against the {_VMEM_LIMIT_BYTES:,}-byte scoped VMEM limit) "
+            f"and gathers their rows with whole-table takes, which Mosaic "
+            f"does not lower. Compiled serving uses hop_pallas_dma "
+            f"(QueryConfig(kernel=True, dma=True)).")
     bq = min(block_q, q)
     assert q % bq == 0, (q, bq)
     grid = (q // bq,)
@@ -226,139 +209,85 @@ def hop_pallas(graph_ids, rev_ids, words, card, tomb, q_words, q_card,
     return out_ids, out_sims, n_scored
 
 
-def _hop_kernel_dma(graph_hbm, rev_hbm, words_hbm, card_hbm, tomb_hbm,
-                    qw_ref, qc_ref, bi_ref, bs_ref,
-                    out_ids_ref, out_sims_ref, nsc_ref, dmab_ref, save_ref,
-                    tomb_s, bidx_s, adj_f, adj_r, cand_s, need_s,
-                    cw_buf, cc_buf, sem_t, sem_a, sem_c,
-                    *, chunk: int, mxu: bool, n_buffers: int):
-    """HBM-resident variant of :func:`_hop_kernel`.
+def dma_row_words(W: int) -> int:
+    """uint32 lanes of one packed DMA row: the W fingerprint words, then
+    the cardinality, zero-padded to whole 128-lane tiles — Mosaic only
+    DMAs rows that fill its lane tiling."""
+    return -(-(W + 1) // 128) * 128
 
-    The five table refs live in ANY/HBM memory and are never read as
-    whole-array values. Per tile the kernel stages (1) the tombstone
-    column once, (2) the beam rows' adjacency lists (one row-DMA per
-    live beam lane), then (3) runs the chunked scoring loop with each
-    chunk's surviving lanes' fingerprint+cardinality rows DMA'd into a
-    rotating ``n_buffers``-deep VMEM scratch buffer — chunk c+1's
-    copies are in flight while chunk c scores. Every DMA start/wait is
-    guarded by the *same* predicate as the suppression mask, so
-    suppressed lanes move zero bytes; the per-row fetched-lane counter
-    rides the loop carry under that predicate, making the emitted
-    ``dma_bytes`` accounting exact by construction.
+
+def _hop_kernel_dma(rows_hbm, qw_ref, qc_ref, bi_ref, bs_ref, ids_ref,
+                    ids_smem,
+                    out_ids_ref, out_sims_ref, nsc_ref, dmab_ref, save_ref,
+                    buf, sem, *, W: int, chunk: int, mxu: bool,
+                    n_buffers: int):
+    """HBM-resident scoring + merge over pre-suppressed candidate lanes.
+
+    ``ids_ref``/``ids_smem`` are the same i32[bq, C] block of lane ids
+    (PAD on every suppressed lane) in VMEM and in SMEM: the vector side
+    builds the scoring mask from it, the per-lane loops read it as
+    scalars to guard each row's DMA. Each chunk's surviving rows are
+    DMA'd from ``rows_hbm`` (packed u32[n, R] rows, :func:`dma_row_words`)
+    into a rotating ``n_buffers``-deep VMEM buffer — chunk c+1's copies
+    are in flight while chunk c scores. The start and wait loops rebuild
+    identical descriptors under the identical guard, so every started
+    copy is waited exactly once; per-slot semaphores keep chunk c+1's
+    signals from satisfying chunk c's waits. Skipped buffer lanes keep
+    whatever bytes a previous chunk left — harmless, the scorer masks
+    them by ``need``.
     """
     beam_ids = bi_ref[...]                              # [bq, B] i32
     beam_sims = bs_ref[...]                             # [bq, B] f32
-    bq, B = beam_ids.shape
-    kg = graph_hbm.shape[1]
-    kr = rev_hbm.shape[1]
-    W = words_hbm.shape[1]
-    row_bytes = W * 4                                   # fingerprint row
+    ids = ids_ref[...]                                  # [bq, C] i32
+    bq, C = ids.shape
+    R = rows_hbm.shape[1]
+    need = ids != PAD_ID
+    n_scored = jnp.sum(need, axis=1, dtype=jnp.int32).reshape(bq, 1)
+    nsc_ref[...] = n_scored
+    # The DMA guard reads the same lane ids the mask is built from, so
+    # rows moved == lanes scored, exactly.
+    dmab_ref[...] = n_scored * (R * 4)
+    save_ref[...] = (C - n_scored) * (R * 4)
 
-    # (t) stage the tombstone column — one contiguous copy per tile.
-    cp = pltpu.make_async_copy(tomb_hbm, tomb_s, sem_t)
-    cp.start()
-    cp.wait()
-    tomb = tomb_s[...][:, 0]                            # [n] i32 (0|1)
-
-    beam_ids, beam_sims = _mask_dead_beam(beam_ids, beam_sims, tomb)
-
-    # (a) adjacency rows by per-lane DMA — PAD/dead beam lanes skipped.
-    # Ids go through scratch so the loop bodies read scalars from a ref.
-    bidx_s[...] = beam_ids.reshape(-1, 1)
-    n_lanes = bq * B
-
-    def _adj_copies(t):
-        v = bidx_s[t, 0]
-        ok = v != PAD_ID
-        row = jnp.where(ok, v, 0)
-        return ok, (pltpu.make_async_copy(graph_hbm.at[row], adj_f.at[t],
-                                          sem_a),
-                    pltpu.make_async_copy(rev_hbm.at[row], adj_r.at[t],
-                                          sem_a))
-
-    def _adj_start(t, _):
-        ok, (cf, cr) = _adj_copies(t)
-
-        @pl.when(ok)
-        def _():
-            cf.start()
-            cr.start()
-        return 0
-
-    def _adj_wait(t, _):
-        ok, (cf, cr) = _adj_copies(t)
-
-        @pl.when(ok)
-        def _():
-            cf.wait()
-            cr.wait()
-        return 0
-
-    jax.lax.fori_loop(0, n_lanes, _adj_start, 0)
-    jax.lax.fori_loop(0, n_lanes, _adj_wait, 0)
-
-    dead = beam_ids[:, :, None] == PAD_ID               # [bq, B, 1]
-    fwd = jnp.where(dead, PAD_ID,
-                    adj_f[...].reshape(bq, B, kg)).reshape(bq, B * kg)
-    rev = jnp.where(dead, PAD_ID,
-                    adj_r[...].reshape(bq, B, kr)).reshape(bq, B * kr)
-    cand = jnp.concatenate([fwd, rev], axis=1)          # [bq, C]
-    C = cand.shape[1]
-
-    cand, need = _suppress(cand, beam_ids, tomb)
-    nsc_ref[...] = jnp.sum(need, axis=1, dtype=jnp.int32).reshape(bq, 1)
-    cand_s[...] = cand
-    need_s[...] = need.astype(jnp.int32)
-
-    # (c) chunked scoring with double-buffered candidate-row DMA. The
-    # start/wait bodies rebuild identical copy descriptors under the
-    # identical `ok` guard, so every started copy is waited exactly once;
-    # per-slot semaphores keep chunk c+1's signals from satisfying chunk
-    # c's waits. Skipped buffer lanes keep whatever bytes a previous
-    # chunk left there — harmless, `score_gathered_chunk` masks by need.
-    qw = qw_ref[...]                                    # [bq, W] u32
+    qw = qw_ref[...]                                    # [bq, R] u32
     qcf = qc_ref[...].astype(jnp.float32)               # [bq, 1]
     q_bits = unpack_bits_int8(qw) if mxu else None
     n_chunks = -(-C // chunk)
 
-    def _lane_copies(t, s, ch, slot):
+    def _lane_copy(t, s, ch, slot):
         i = t // ch
         j = t % ch
-        ok = need_s[i, s + j] > 0
-        row = jnp.where(ok, cand_s[i, s + j], 0)
-        return i, ok, (
-            pltpu.make_async_copy(words_hbm.at[row],
-                                  cw_buf.at[slot, i, j], sem_c.at[slot]),
-            pltpu.make_async_copy(card_hbm.at[row],
-                                  cc_buf.at[slot, i, j], sem_c.at[slot]))
+        v = ids_smem[i, s + j]
+        ok = v != PAD_ID
+        row = jnp.where(ok, v, 0)
+        return ok, pltpu.make_async_copy(rows_hbm.at[row],
+                                         buf.at[slot, i, j], sem.at[slot])
 
-    def start_chunk(ci, slot, cnt):
+    def start_chunk(ci, slot):
         s = ci * chunk
         ch = min(chunk, C - s)
 
-        def body(t, acc):
-            i, ok, (cw, cc) = _lane_copies(t, s, ch, slot)
+        def body(t, carry):
+            ok, cp = _lane_copy(t, s, ch, slot)
 
             @pl.when(ok)
             def _():
-                cw.start()
-                cc.start()
-            return acc.at[i].add(ok.astype(jnp.int32))
+                cp.start()
+            return carry
 
-        return jax.lax.fori_loop(0, bq * ch, body, cnt)
+        jax.lax.fori_loop(0, bq * ch, body, 0)
 
     def wait_chunk(ci, slot):
         s = ci * chunk
         ch = min(chunk, C - s)
 
-        def body(t, _):
-            _, ok, (cw, cc) = _lane_copies(t, s, ch, slot)
+        def body(t, carry):
+            ok, cp = _lane_copy(t, s, ch, slot)
 
             @pl.when(ok)
             def _():
-                cw.wait()
-                cc.wait()
-            return 0
+                cp.wait()
+            return carry
 
         jax.lax.fori_loop(0, bq * ch, body, 0)
 
@@ -366,38 +295,37 @@ def _hop_kernel_dma(graph_hbm, rev_hbm, words_hbm, card_hbm, tomb_hbm,
         s = ci * chunk
         ch = min(chunk, C - s)
         need_c = need[:, s:s + ch]
-        cw = cw_buf[slot, :, :ch].reshape(bq * ch, W)
-        cc = jnp.where(need_c, cc_buf[slot, :, :ch, 0],
-                       0).astype(jnp.float32)
+        cw = buf[slot, :, :ch, :]                       # [bq, ch, R] u32
+        lane = jax.lax.broadcasted_iota(jnp.int32, cw.shape, 2)
+        card = jnp.sum(jnp.where(
+            lane == W, jax.lax.bitcast_convert_type(cw, jnp.int32), 0),
+            axis=-1)
+        cc = jnp.where(need_c, card, 0).astype(jnp.float32)
+        # The query's lanes past W are zero, so the packed cardinality
+        # and padding lanes add nothing to the intersection.
         return score_gathered_chunk(qw, qcf, q_bits, cw, cc, need_c,
                                     mxu=mxu)
 
-    fetched = jnp.zeros((bq,), jnp.int32)
     sims_chunks = []
     if n_buffers > 1:
-        fetched = start_chunk(0, 0, fetched)
+        start_chunk(0, 0)
         for ci in range(n_chunks):
             if ci + 1 < n_chunks:
-                fetched = start_chunk(ci + 1, (ci + 1) % n_buffers, fetched)
+                start_chunk(ci + 1, (ci + 1) % n_buffers)
             wait_chunk(ci, ci % n_buffers)
             sims_chunks.append(score_chunk(ci, ci % n_buffers))
     else:
         # n_buffers == 1: no overlap — a degenerate tuning point kept
         # for the autotuner's smallest-VMEM configurations.
         for ci in range(n_chunks):
-            fetched = start_chunk(ci, 0, fetched)
+            start_chunk(ci, 0)
             wait_chunk(ci, 0)
             sims_chunks.append(score_chunk(ci, 0))
     cand_sims = jnp.concatenate(sims_chunks, axis=1)
 
-    # Byte accounting: fingerprint bytes only (the cardinality scalar
-    # rides the same guard but is excluded — W·4 per row is the traffic
-    # the memory hierarchy cares about). `fetched == n_scored` holds by
-    # construction; tests assert dma_bytes == n_scored·W·4.
-    dmab_ref[...] = (fetched * row_bytes).reshape(bq, 1)
-    save_ref[...] = ((C - fetched) * row_bytes).reshape(bq, 1)
-
-    _merge(beam_ids, beam_sims, cand, cand_sims, out_ids_ref, out_sims_ref)
+    # Suppressed lanes carry PAD ids here, not their original ids: they
+    # score −inf, and an id on a −inf lane never reaches the output.
+    _merge(beam_ids, beam_sims, ids, cand_sims, out_ids_ref, out_sims_ref)
 
 
 @functools.partial(
@@ -406,44 +334,53 @@ def _hop_kernel_dma(graph_hbm, rev_hbm, words_hbm, card_hbm, tomb_hbm,
 )
 def hop_pallas_dma(graph_ids, rev_ids, words, card, tomb, q_words, q_card,
                    beam_ids, beam_sims, *,
-                   block_q: int = 16, chunk: int = 64,
+                   block_q: int = 16, chunk: int = 128,
                    mxu: bool = False, n_buffers: int = 2,
                    interpret: bool = True):
-    """Memory-hierarchy-aware fused hop: HBM tables, per-chunk DMA.
+    """Memory-hierarchy-aware fused hop: HBM rows, per-chunk DMA.
 
     Same contract as :func:`hop_pallas` (and bitwise-identical to it and
     to ``ref.descent_hop_ref``), plus two extra outputs:
-    ``dma_bytes i32[q, 1]`` — fingerprint bytes actually DMA'd for this
-    hop per query — and ``bytes_saved i32[q, 1]`` — bytes the
-    suppressed lanes did *not* move vs the unfused ``beam·(kg+kr)``
-    gather. ``(block_q, chunk, n_buffers)`` come from
-    ``tune.hop_params`` via ops.py; VMEM scratch is
-    ``n_buffers·block_q·chunk·(W+1)·4`` bytes for the rotating row
-    buffers plus the adjacency/id staging (see README "Kernels").
+    ``dma_bytes i32[q, 1]`` — packed-row bytes actually DMA'd for this
+    hop per query — and ``bytes_saved i32[q, 1]`` — the bytes the
+    suppressed lanes did *not* move out of the ``beam·(kg+kr)`` lanes.
+    ``(block_q, chunk, n_buffers)`` come from ``tune.hop_params`` via
+    ops.py; VMEM scratch is ``n_buffers·block_q·chunk·R·4`` bytes for
+    the rotating row buffers, ``R = dma_row_words(W)``.
     """
     q, B = beam_ids.shape
     n, W = words.shape
     kg, kr = graph_ids.shape[1], rev_ids.shape[1]
     C = B * (kg + kr)
+    R = dma_row_words(W)
     bq = min(block_q, q)
     assert q % bq == 0, (q, bq)
     nb = max(1, min(n_buffers, -(-C // chunk)))
-    grid = (q // bq,)
+    ch = min(chunk, C)
 
-    outs = pl.pallas_call(
-        functools.partial(_hop_kernel_dma, chunk=chunk, mxu=mxu,
+    # (a)+(b) as XLA ops: gather lane ids, retire tombstoned, PAD and
+    # in-beam lanes. Dead beam lanes leave the beam here too.
+    beam_ids, beam_sims, cand = hop_candidates(
+        graph_ids, rev_ids, beam_ids, beam_sims, tomb[:, 0] > 0)
+    lane_ids = jnp.where(_not_in_beam(cand, beam_ids), cand, PAD_ID)
+    rows = jnp.concatenate(
+        [words, card.astype(jnp.uint32),
+         jnp.zeros((n, R - W - 1), jnp.uint32)], axis=1)
+    qw = jnp.pad(q_words, ((0, 0), (0, R - W)))
+
+    return pl.pallas_call(
+        functools.partial(_hop_kernel_dma, W=W, chunk=chunk, mxu=mxu,
                           n_buffers=nb),
-        grid=grid,
+        grid=(q // bq,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),       # graph_ids
-            pl.BlockSpec(memory_space=pltpu.ANY),       # rev_ids
-            pl.BlockSpec(memory_space=pltpu.ANY),       # words
-            pl.BlockSpec(memory_space=pltpu.ANY),       # card
-            pl.BlockSpec(memory_space=pltpu.ANY),       # tomb
-            pl.BlockSpec((bq, W), lambda i: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),          # packed rows
+            pl.BlockSpec((bq, R), lambda i: (i, 0)),
             pl.BlockSpec((bq, 1), lambda i: (i, 0)),
             pl.BlockSpec((bq, B), lambda i: (i, 0)),
             pl.BlockSpec((bq, B), lambda i: (i, 0)),
+            pl.BlockSpec((bq, C), lambda i: (i, 0)),
+            pl.BlockSpec((bq, C), lambda i: (i, 0),
+                         memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((bq, B), lambda i: (i, 0)),
@@ -460,19 +397,8 @@ def hop_pallas_dma(graph_ids, rev_ids, words, card, tomb, q_words, q_card,
             jax.ShapeDtypeStruct((q, 1), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((n, 1), jnp.int32),              # tomb_s
-            pltpu.VMEM((bq * B, 1), jnp.int32),         # bidx_s
-            pltpu.VMEM((bq * B, kg), jnp.int32),        # adj_f
-            pltpu.VMEM((bq * B, kr), jnp.int32),        # adj_r
-            pltpu.VMEM((bq, C), jnp.int32),             # cand_s
-            pltpu.VMEM((bq, C), jnp.int32),             # need_s
-            pltpu.VMEM((nb, bq, min(chunk, C), W), jnp.uint32),  # cw_buf
-            pltpu.VMEM((nb, bq, min(chunk, C), 1), jnp.int32),   # cc_buf
-            pltpu.SemaphoreType.DMA,                    # sem_t
-            pltpu.SemaphoreType.DMA,                    # sem_a
-            pltpu.SemaphoreType.DMA((nb,)),             # sem_c
+            pltpu.VMEM((nb, bq, ch, R), jnp.uint32),    # row buffers
+            pltpu.SemaphoreType.DMA((nb,)),
         ],
         interpret=interpret,
-    )(graph_ids, rev_ids, words, card, tomb, q_words, q_card,
-      beam_ids, beam_sims)
-    return outs
+    )(rows, qw, q_card, beam_ids, beam_sims, lane_ids, lane_ids)

@@ -4,7 +4,7 @@ Handles query-row padding to block multiples, card reshaping to the
 kernel's 2-D layout, the popcount-vs-MXU layout choice by sketch width,
 and the VMEM-vs-DMA placement choice (``dma=``). Launch parameters are
 resolved at plain-Python level — interpret mode through
-``repro.kernels.config`` (``$REPRO_PALLAS_INTERPRET``), DMA tile shapes
+``repro.kernels.config`` (chosen by the backend), DMA tile shapes
 through the shape-keyed ``tune`` cache — then handed to an inner jit as
 static arguments. ``descent_hop`` itself is *not* jitted: it runs at
 trace time of whatever jitted program calls it (wave scan, slot hop,
@@ -95,9 +95,9 @@ def descent_hop(graph_ids, rev_ids, words, card, q_words, q_card,
     With ``with_counts`` returns a 5-tuple ``(ids, sims, n_scored,
     dma_bytes, bytes_saved)``, each i32[q] per query for this hop:
     lanes that survived in-tile suppression and were scored (the
-    unfused path always scores ``beam·(kg+kr)``), fingerprint bytes
-    DMA'd (``n_scored·W·4`` for the DMA placement, 0 for VMEM), and
-    fingerprint bytes the suppression skipped at the DMA level.
+    unfused path always scores ``beam·(kg+kr)``), packed-row bytes
+    DMA'd (``n_scored·4·dma_row_words(W)`` for the DMA placement, 0 for
+    VMEM), and the bytes the suppression skipped at the DMA level.
     """
     q = beam_ids.shape[0]
     B = beam_ids.shape[1]

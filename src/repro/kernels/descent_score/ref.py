@@ -50,6 +50,29 @@ def mask_dead(tomb, ids, sims=None):
     return out_ids, jnp.where(dead, NEG_INF, sims)
 
 
+def hop_candidates(graph_ids, rev_ids, beam_ids, beam_sims, tomb=None):
+    """The gather half of a hop: ``(beam_ids, beam_sims, cand)``.
+
+    Dead beam lanes drop to PAD/−inf, and ``cand`` i32[q, beam·(kg+kr)]
+    holds the forward then reverse neighbors of each beam lane in
+    ``[fwd | rev]`` column order, with PAD under PAD beam lanes and on
+    lanes naming tombstoned rows (``tomb`` bool[n] or None).
+    """
+    if tomb is not None:
+        beam_ids, beam_sims = mask_dead(tomb, beam_ids, beam_sims)
+    nq = beam_ids.shape[0]
+    kg, kr = graph_ids.shape[1], rev_ids.shape[1]
+    safe = jnp.where(beam_ids == PAD_ID, 0, beam_ids)
+    fwd = graph_ids[safe].reshape(nq, -1)
+    fwd = jnp.where((beam_ids == PAD_ID).repeat(kg, axis=1), PAD_ID, fwd)
+    rev = rev_ids[safe].reshape(nq, -1)
+    rev = jnp.where((beam_ids == PAD_ID).repeat(kr, axis=1), PAD_ID, rev)
+    cand = jnp.concatenate([fwd, rev], axis=1)      # [q, beam·(kg+kr)]
+    if tomb is not None:
+        cand = mask_dead(tomb, cand)
+    return beam_ids, beam_sims, cand
+
+
 def descent_hop_ref(graph_ids, rev_ids, words, card,
                     q_words, q_card, beam_ids, beam_sims, tomb=None):
     """One friend-of-a-friend hop, unfused: gather → score ALL lanes →
@@ -61,20 +84,9 @@ def descent_hop_ref(graph_ids, rev_ids, words, card,
     deleted rows score nothing) — the same pre-masking the fused kernel
     applies, so the bitwise ref↔kernel equivalence is unchanged.
     """
-    if tomb is not None:
-        beam_ids, beam_sims = mask_dead(tomb, beam_ids, beam_sims)
-    nq = q_words.shape[0]
-    kg, kr = graph_ids.shape[1], rev_ids.shape[1]
-    score = row_scorer(words, card)
-    safe = jnp.where(beam_ids == PAD_ID, 0, beam_ids)
-    fwd = graph_ids[safe].reshape(nq, -1)
-    fwd = jnp.where((beam_ids == PAD_ID).repeat(kg, axis=1), PAD_ID, fwd)
-    rev = rev_ids[safe].reshape(nq, -1)
-    rev = jnp.where((beam_ids == PAD_ID).repeat(kr, axis=1), PAD_ID, rev)
-    cand = jnp.concatenate([fwd, rev], axis=1)      # [q, beam·(kg+kr)]
-    if tomb is not None:
-        cand = mask_dead(tomb, cand)
-    cand_sims = score(q_words, q_card, cand)
+    beam_ids, beam_sims, cand = hop_candidates(graph_ids, rev_ids,
+                                               beam_ids, beam_sims, tomb)
+    cand_sims = row_scorer(words, card)(q_words, q_card, cand)
     return merge_topk(
         jnp.concatenate([beam_ids, cand], axis=1),
         jnp.concatenate([beam_sims, cand_sims], axis=1),

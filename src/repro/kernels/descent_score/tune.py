@@ -20,9 +20,10 @@ pressure. This module replaces the fixed constants with a small tuner:
 * ``stats`` counts hits/misses for CI gates.
 
 The heuristic targets a scratch budget: the rotating row buffers cost
-``n_buffers·block_q·score_chunk·(W+1)·4`` bytes and must leave room for
-the adjacency staging (``block_q·beam·(kg+kr+2)·4``) and the staged
-tombstone column (``n·4``) inside a few MB of VMEM.
+``n_buffers·block_q·score_chunk·R·4`` bytes (``R = dma_row_words(W)``,
+the packed 128-lane row) and must leave room for the lane-id block
+(``block_q·beam·(kg+kr)·4``, once in VMEM and once in SMEM) and the
+merge inside a few MB of VMEM.
 """
 from __future__ import annotations
 
@@ -31,6 +32,7 @@ import json
 import os
 import threading
 
+from repro.kernels.descent_score.descent_score import dma_row_words
 from repro.sketch.goldfinger import MXU_MIN_WORDS
 
 ENV_CACHE = "REPRO_TUNE_CACHE"
@@ -69,7 +71,7 @@ def _heuristic(n: int, W: int, beam: int, kdeg: int) -> HopParams:
     # with more queries per tile.
     block_q = 8 if mxu else 16
     # Largest power-of-two chunk that fits the double-buffered budget.
-    row_bytes = (W + 1) * 4
+    row_bytes = dma_row_words(W) * 4
     chunk = 128
     while chunk > 16 and 2 * block_q * chunk * row_bytes > _SCRATCH_BUDGET:
         chunk //= 2
@@ -117,12 +119,9 @@ def _save_disk() -> None:
         for key, p in sorted(_measured.items())
     }
     tmp = f"{path}.tmp.{os.getpid()}"
-    try:
-        with open(tmp, "w") as fh:
-            json.dump(payload, fh, indent=2, sort_keys=True)
-        os.replace(tmp, path)
-    except OSError:
-        pass
+    with open(tmp, "w") as fh:
+        json.dump(payload, fh, indent=2, sort_keys=True)
+    os.replace(tmp, path)
 
 
 def record(key: tuple[int, int, int, int], params: HopParams) -> None:

@@ -1,7 +1,7 @@
 """jit'd public wrapper for the frh_minhash kernel.
 
 Interpret-vs-compiled resolves per call through
-``repro.kernels.config`` (``$REPRO_PALLAS_INTERPRET``).
+``repro.kernels.config``: interpreted on the CPU backend, compiled on TPU.
 """
 from __future__ import annotations
 

@@ -2,10 +2,9 @@
 
 Handles bit-plane unpacking, padding to block multiples, and the batched
 per-cluster entry point used by core/local_knn. Interpret-vs-compiled is
-resolved per call through ``repro.kernels.config``
-(``$REPRO_PALLAS_INTERPRET``, default interpret — this container is
-CPU); the flag is a static arg of the inner jit, so flipping it
-re-traces instead of reusing a stale cache entry.
+resolved per call through ``repro.kernels.config`` (interpreted on the
+CPU backend, compiled on TPU); the flag is a static arg of the inner
+jit, so flipping it re-traces instead of reusing a stale cache entry.
 """
 from __future__ import annotations
 

@@ -32,7 +32,7 @@ def score_gathered_chunk(qw, qcf, q_bits, cw, ccf, need_c, *, mxu: bool):
 
     qw u32[bq, W] query fingerprints; qcf f32[bq, 1] query cardinalities;
     q_bits int8[bq, W·32] pre-unpacked bit planes (only read when
-    ``mxu``); cw u32[bq·ch, W] gathered candidate rows, lane-major;
+    ``mxu``); cw u32[bq, ch, W] gathered candidate rows;
     ccf f32[bq, ch] candidate cardinalities (0 on suppressed lanes);
     need_c bool[bq, ch] surviving-lane mask. Returns f32[bq, ch] sims
     with ``NEG_INF`` on suppressed lanes. Suppressed lanes may hold
@@ -45,7 +45,7 @@ def score_gathered_chunk(qw, qcf, q_bits, cw, ccf, need_c, *, mxu: bool):
     if mxu:
         # Tile-dense bit-plane matmul: chunk candidates × ALL tile
         # queries on the MXU, keep the per-row diagonal.
-        c_bits = unpack_bits_int8(cw)                   # [bq·ch, W·32]
+        c_bits = unpack_bits_int8(cw.reshape(bq * ch, W))  # [bq·ch, W·32]
         inter3 = jax.lax.dot_general(
             c_bits, q_bits, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.int32,
@@ -55,10 +55,11 @@ def score_gathered_chunk(qw, qcf, q_bits, cw, ccf, need_c, *, mxu: bool):
         inter = jnp.sum(jnp.where(own == qid, inter3, 0),
                         axis=-1).astype(jnp.float32)
     else:
+        # Per-word counts are <= 32, so the int32 sum is exact (Mosaic
+        # has no unsigned reduction).
         inter = jnp.sum(
-            jax.lax.population_count(qw[:, None, :]
-                                     & cw.reshape(bq, ch, W)),
-            axis=-1).astype(jnp.float32)                # [bq, ch]
+            jax.lax.population_count(qw[:, None, :] & cw)
+            .astype(jnp.int32), axis=-1).astype(jnp.float32)  # [bq, ch]
     union = qcf + ccf - inter
     s_c = jnp.where(union > 0, inter / jnp.maximum(union, 1.0), 0.0)
     return jnp.where(need_c, s_c, NEG_INF)

@@ -16,6 +16,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.clustering import build_plan
 from repro.core.local_knn import local_knn
 from repro.core.merge import merge_partial
@@ -86,6 +87,7 @@ def main(argv=None):
                     help="save a servable KNNIndex (.npz) for knn_serve")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     ds = make_dataset(args.dataset, scale=args.scale, seed=args.seed)
     params = params_for(args.dataset, k=args.k)

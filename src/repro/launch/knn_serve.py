@@ -69,6 +69,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.params import params_for
 from repro.data.synthetic import make_dataset
 from repro.faults.plan import EngineCrash
@@ -161,6 +162,7 @@ def main(argv=None):
     ap.add_argument("--save-index", default=None, help="save the built index")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     faults = None
     if args.fault_plan:

@@ -396,7 +396,6 @@ def apply_moe(p, x, cfg, ctx: ShardCtx):
                             p["w_down"], cap, 0, dt)
     else:
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
         n_model = ctx.model_size
         n_batch = int(np.prod([ctx.mesh.shape[a] for a in ctx.batch_axes]))
@@ -412,7 +411,7 @@ def apply_moe(p, x, cfg, ctx: ShardCtx):
 
         eidx = jnp.arange(n_model, dtype=jnp.int32)
         ba = ctx.batch_axes
-        out = shard_map(
+        out = jax.shard_map(
             local, mesh=ctx.mesh,
             in_specs=(P(ba, None), P(ba, None), P(ba, None),
                       P(ctx.model_axis, None, None),
@@ -420,7 +419,7 @@ def apply_moe(p, x, cfg, ctx: ShardCtx):
                       P(ctx.model_axis, None, None),
                       P(ctx.model_axis)),
             out_specs=P(ba, None),
-            check_rep=False,
+            check_vma=False,
         )(xt, gate_w, gate_e, p["w_gate"].astype(dt),
           p["w_up"].astype(dt), p["w_down"].astype(dt), eidx)
         out = ad_checkpoint.checkpoint_name(out, "tp_out")

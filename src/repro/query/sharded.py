@@ -630,6 +630,12 @@ class ShardedDescent:
         self.last_hop_stats = np.asarray(jnp.sum(stats, axis=0))
         return _merge_shard_topk(ids, sims, k)
 
+    @property
+    def devices(self) -> set:
+        """Devices holding the resident shard tensors (one per shard on
+        a mesh, the single default device otherwise)."""
+        return {d for a in self._dev for d in a.devices()}
+
     def shard_beam(self, beam: int, k: int) -> int:
         """Per-shard frontier width for a fleet-level ``beam``."""
         return max(k, int(np.ceil(self.oversample * beam / self.n_shards)))
@@ -687,7 +693,6 @@ def _mesh_program(mesh, *, k, beam, hops, kernel=False, dma=False,
     names), so resharding after an insert burst reuses the compiled
     program as long as shapes and (k, beam, hops) are unchanged —
     symmetric with the module-level jitted ``_vmapped_descent``."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def device_fn(g, r, w, c, m, t, qw, qc, s):
@@ -705,8 +710,8 @@ def _mesh_program(mesh, *, k, beam, hops, kernel=False, dma=False,
                 P(), P(), P("shards", None, None))
     out_specs = (P("shards", None, None), P("shards", None, None),
                  P("shards", None, None))
-    return jax.jit(shard_map(device_fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(device_fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

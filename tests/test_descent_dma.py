@@ -8,9 +8,10 @@ inputs: sketch widths straddling the popcount→MXU boundary, score
 chunks that do not divide the lane count, all-suppressed chunks,
 tombstone-heavy tables, single- and double-buffered pipelines. On top
 of parity, the byte accounting must be exact (``dma_bytes`` ==
-``n_scored·W·4``; ``bytes_saved`` the complement over the full
-candidate count) and the shape-keyed autotuner must keep the serving
-plans compile-once across admissions and reshards.
+``n_scored`` packed rows of ``4·dma_row_words(W)`` bytes;
+``bytes_saved`` the complement over the full candidate count) and the
+shape-keyed autotuner must keep the serving plans compile-once across
+admissions and reshards.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +22,7 @@ from repro.data.synthetic import make_dataset
 from repro.kernels.descent_score import ops as ds_ops
 from repro.kernels.descent_score import ref as ds_ref
 from repro.kernels.descent_score import tune
+from repro.kernels.descent_score.descent_score import dma_row_words
 from repro.query.engine import QueryConfig, QueryEngine, QueryRequest
 from repro.query.index import build_index
 from repro.sched import trace
@@ -57,7 +59,7 @@ def _assert_dma_parity(args, tomb=None, **dma_kw):
     """ids AND sims bitwise vs the jnp oracle and the VMEM kernel, plus
     exact byte accounting against the scored-lane counter."""
     B = args[6].shape[1]
-    W = args[2].shape[1]
+    row_bytes = 4 * dma_row_words(args[2].shape[1])
     C = B * (args[0].shape[1] + args[1].shape[1])
     ri, rs = ds_ref.descent_hop_ref(*args, tomb=tomb)
     ki, ks, nsc, kb, ksv = ds_ops.descent_hop(*args, tomb=tomb,
@@ -73,9 +75,9 @@ def _assert_dma_parity(args, tomb=None, **dma_kw):
     np.testing.assert_array_equal(np.asarray(dnsc), np.asarray(nsc))
     assert (np.asarray(kb) == 0).all() and (np.asarray(ksv) == 0).all()
     np.testing.assert_array_equal(np.asarray(dmab),
-                                  np.asarray(dnsc) * W * 4)
+                                  np.asarray(dnsc) * row_bytes)
     np.testing.assert_array_equal(np.asarray(saved),
-                                  (C - np.asarray(dnsc)) * W * 4)
+                                  (C - np.asarray(dnsc)) * row_bytes)
     return np.asarray(dnsc), np.asarray(dmab), np.asarray(saved)
 
 
@@ -144,7 +146,7 @@ def test_dma_all_suppressed_chunks():
     nsc, dmab, saved = _assert_dma_parity(args, score_chunk=5)
     assert (nsc == 0).all()
     assert (dmab == 0).all()
-    assert (saved == C * W * 4).all()
+    assert (saved == C * 4 * dma_row_words(W)).all()
 
 
 def test_dma_tombstone_heavy():
@@ -206,7 +208,7 @@ def test_plan_matrix_dma_bitwise(index, query_profiles, placement,
     W = index.words.shape[1]
     # The DMA guard predicate IS the scoring mask: bytes moved must
     # agree with lanes scored exactly.
-    assert d["dma_bytes"] == d["scored_lanes"] * W * 4
+    assert d["dma_bytes"] == d["scored_lanes"] * 4 * dma_row_words(W)
 
 
 # -- autotuner / compile-once ----------------------------------------------

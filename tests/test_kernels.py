@@ -152,27 +152,39 @@ def test_interpret_flag_shared_by_all_kernel_packages():
 
 
 def test_interpret_env_parsing(monkeypatch):
+    """The platform decides, not the environment: CPU interprets, TPU
+    compiles, any other backend is refused, and the retired
+    ``REPRO_PALLAS_INTERPRET`` variable has no effect."""
     from repro.kernels import config
 
     monkeypatch.setattr(config, "_override", None)
-    for raw, expect in [(None, True), ("1", True), ("yes", True),
-                        ("weird", True), ("0", False), ("false", False),
-                        ("No", False), (" OFF ", False)]:
+    for raw in (None, "0", "1"):
         if raw is None:
-            monkeypatch.delenv(config.ENV_VAR, raising=False)
+            monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
         else:
-            monkeypatch.setenv(config.ENV_VAR, raw)
-        assert config.interpret_mode() is expect, raw
+            monkeypatch.setenv("REPRO_PALLAS_INTERPRET", raw)
+        for backend, expect in (("cpu", True), ("tpu", False)):
+            monkeypatch.setattr(config.jax, "default_backend",
+                                lambda b=backend: b)
+            assert config.interpret_mode() is expect, (raw, backend)
+        monkeypatch.setattr(config.jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            config.interpret_mode()
 
 
 def test_interpret_override_beats_env(monkeypatch):
+    """``set_interpret`` pins the mode over the platform rule (on either
+    backend), and ``set_interpret(None)`` hands control back to it."""
     from repro.kernels import config
 
-    monkeypatch.setenv(config.ENV_VAR, "0")
+    monkeypatch.setattr(config.jax, "default_backend", lambda: "tpu")
     config.set_interpret(True)
     try:
         assert config.interpret_mode() is True
-        config.set_interpret(None)  # back to env-driven
+        config.set_interpret(None)  # back to the platform rule
+        assert config.interpret_mode() is False
+        monkeypatch.setattr(config.jax, "default_backend", lambda: "cpu")
+        config.set_interpret(False)
         assert config.interpret_mode() is False
     finally:
         config.set_interpret(None)
