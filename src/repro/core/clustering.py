@@ -15,6 +15,7 @@ import numpy as np
 from repro.core import hashing
 from repro.core.params import C2Params
 from repro.core.splitting import SplitResult, split_config
+from repro.sched import trace
 from repro.types import Dataset
 
 
@@ -52,20 +53,23 @@ def frh_seeds(params: C2Params) -> np.ndarray:
 
 def build_plan(ds: Dataset, params: C2Params) -> ClusterPlan:
     """Cluster all users under t FastRandomHash functions + recursive split."""
-    seeds = frh_seeds(params)
-    item_h = hashing.item_hashes(ds.items, seeds, params.b)  # [t, nnz]
-    cands = hashing.user_distinct_hashes_np(item_h, ds.offsets, params.split_depth)
-
-    members: list[np.ndarray] = []
-    config_of: list[int] = []
-    paths: list[tuple[int, ...]] = []
-    for i in range(params.t):
-        res: SplitResult = split_config(cands[i], params.max_cluster)
-        for mem, path in zip(res.members, res.paths):
-            if len(mem) >= 2:  # singleton clusters yield no edges
-                members.append(mem)
-                config_of.append(i)
-                paths.append(path)
+    with trace.span("repro.cluster"):
+        seeds = frh_seeds(params)
+        with trace.span("repro.cluster.hash"):
+            item_h = hashing.item_hashes(ds.items, seeds, params.b)  # [t,nnz]
+            cands = hashing.user_distinct_hashes_np(item_h, ds.offsets,
+                                                    params.split_depth)
+        members: list[np.ndarray] = []
+        config_of: list[int] = []
+        paths: list[tuple[int, ...]] = []
+        with trace.span("repro.cluster.split"):
+            for i in range(params.t):
+                res: SplitResult = split_config(cands[i], params.max_cluster)
+                for mem, path in zip(res.members, res.paths):
+                    if len(mem) >= 2:  # singleton clusters yield no edges
+                        members.append(mem)
+                        config_of.append(i)
+                        paths.append(path)
     return ClusterPlan(
         members=members,
         config_of=np.array(config_of, dtype=np.int32),

@@ -140,23 +140,16 @@ def distributed_local_knn(plan: ClusterPlan, gf: GoldFinger,
 def distributed_c2(ds, params: C2Params, mesh, gf: GoldFinger | None = None,
                    data_axis: str = "data"):
     """Full distributed pipeline: host plan → mesh Step 2 → merge."""
-    import time
-
     from repro.core.clustering import build_plan
     from repro.core.merge import merge_partial
     from repro.sketch.goldfinger import fingerprint_dataset
 
-    t0 = time.perf_counter()
     if gf is None:
         gf = fingerprint_dataset(ds, n_bits=params.n_bits, seed=params.seed)
     plan = build_plan(ds, params)
-    t1 = time.perf_counter()
     ids, sims, dp = distributed_local_knn(plan, gf, params, mesh, data_axis)
-    t2 = time.perf_counter()
     graph = merge_partial(ids, sims, params.k)
-    t3 = time.perf_counter()
     stats = {
-        "t_cluster": t1 - t0, "t_local": t2 - t1, "t_merge": t3 - t2,
         "n_clusters": plan.n_clusters,
         "n_sims": plan.brute_force_sims(),
         "lpt_imbalance": dp.imbalance,

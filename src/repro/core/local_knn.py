@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.core.clustering import ClusterPlan
 from repro.core.params import C2Params
+from repro.sched import trace
 from repro.sketch.goldfinger import GoldFinger, jaccard_pairwise_auto
 from repro.types import NEG_INF, PAD_ID
 
@@ -94,57 +95,72 @@ def local_knn(plan: ClusterPlan, gf: GoldFinger, params: C2Params):
     the cluster was smaller than k+1 or the user was unclustered).
     """
     t, n, k = plan.t, plan.n_users, params.k
-    out_ids = np.full((t, n, k), PAD_ID, dtype=np.int32)
-    out_sims = np.full((t, n, k), NEG_INF, dtype=np.float32)
+    with trace.span("repro.local_knn"):
+        out_ids = np.full((t, n, k), PAD_ID, dtype=np.int32)
+        out_sims = np.full((t, n, k), NEG_INF, dtype=np.float32)
 
-    sizes = plan.sizes
-    # Alg. 2 switch: brute force iff |C| < ρk².
-    greedy_idx = np.flatnonzero(sizes >= params.bf_threshold)
-    for ci in greedy_idx:
-        cfg = plan.config_of[ci]
-        users = plan.members[ci]
-        nbr, sims = _hyrec_cluster(users, gf, k, max_iters=params.rho)
-        out_ids[cfg, users] = nbr
-        out_sims[cfg, users] = sims
+        sizes = plan.sizes
+        # Alg. 2 switch: brute force iff |C| < ρk².
+        greedy_idx = np.flatnonzero(sizes >= params.bf_threshold)
+        if len(greedy_idx):
+            with trace.span("repro.local_knn.hyrec"):
+                for ci in greedy_idx:
+                    cfg = plan.config_of[ci]
+                    users = plan.members[ci]
+                    nbr, sims = _hyrec_cluster(users, gf, k,
+                                               max_iters=params.rho)
+                    out_ids[cfg, users] = nbr
+                    out_sims[cfg, users] = sims
 
-    brute = np.ones(len(sizes), dtype=bool)
-    brute[greedy_idx] = False
-    caps = np.array([capacity_of(int(s)) for s in sizes], dtype=np.int64)
-    caps = np.where(brute, caps, -1)  # exclude greedy clusters below
-    words_h = np.asarray(gf.words)
-    card_h = np.asarray(gf.card)
-    W = words_h.shape[1]
+        brute = np.ones(len(sizes), dtype=bool)
+        brute[greedy_idx] = False
+        caps = np.array([capacity_of(int(s)) for s in sizes], dtype=np.int64)
+        caps = np.where(brute, caps, -1)  # exclude greedy clusters below
+        words_h = np.asarray(gf.words)
+        card_h = np.asarray(gf.card)
+        W = words_h.shape[1]
 
-    # Bound per-group batch memory: sims [m, cap, cap] f32 AND the
-    # gathered fingerprints [m, cap, W] (wide in raw-incidence mode).
-    sim_budget = 256 << 20  # 256 MB
+        # Bound per-group batch memory: sims [m, cap, cap] f32 AND the
+        # gathered fingerprints [m, cap, W] (wide in raw-incidence mode).
+        sim_budget = 256 << 20  # 256 MB
 
-    for cap in np.unique(caps):
-        if cap < 0:
-            continue
-        idx = np.flatnonzero(caps == cap)
-        m_max = max(1, int(sim_budget // max(cap * cap * 4,
-                                             cap * W * 4 * 4)))
-        for s in range(0, len(idx), m_max):
-            batch = idx[s:s + m_max]
-            # Pad the cluster count to a power of two so each (capacity, m)
-            # group shape compiles once, not once per batch remainder.
-            m = capacity_of(len(batch), minimum=1)
-            mem = np.full((m, cap), PAD_ID, dtype=np.int32)
-            for j, ci in enumerate(batch):
-                mem[j, : sizes[ci]] = plan.members[ci]
-            gmem = np.where(mem == PAD_ID, 0, mem)
-            w = words_h[gmem].reshape(m, cap, W)
-            c = np.where(mem == PAD_ID, 0, card_h[gmem])
-            fn = _pallas_group_knn if params.use_pallas else _group_knn
-            nbr, sims = fn(jnp.asarray(w), jnp.asarray(c), jnp.asarray(mem), k)
-            nbr = np.asarray(nbr)[: len(batch)]
-            sims = np.asarray(sims)[: len(batch)]
-            # Scatter back per configuration (each user appears in exactly
-            # one cluster per configuration).
-            for j, ci in enumerate(batch):
-                cfg = plan.config_of[ci]
-                users = plan.members[ci]
-                out_ids[cfg, users] = nbr[j, : len(users)]
-                out_sims[cfg, users] = sims[j, : len(users)]
+        for cap in np.unique(caps):
+            if cap < 0:
+                continue
+            idx = np.flatnonzero(caps == cap)
+            m_max = max(1, int(sim_budget // max(cap * cap * 4,
+                                                 cap * W * 4 * 4)))
+            for s in range(0, len(idx), m_max):
+                batch = idx[s:s + m_max]
+                # Pad the cluster count to a power of two so each
+                # (capacity, m) group shape compiles once, not once per
+                # batch remainder.
+                m = capacity_of(len(batch), minimum=1)
+                useful = 0  # ordered pairs of distinct co-members
+                with trace.span("repro.local_knn.gather"):
+                    mem = np.full((m, cap), PAD_ID, dtype=np.int32)
+                    for j, ci in enumerate(batch):
+                        size = int(sizes[ci])
+                        mem[j, :size] = plan.members[ci]
+                        useful += size * (size - 1)
+                    gmem = np.where(mem == PAD_ID, 0, mem)
+                    w = words_h[gmem].reshape(m, cap, W)
+                    c = np.where(mem == PAD_ID, 0, card_h[gmem])
+                fn = _pallas_group_knn if params.use_pallas else _group_knn
+                with trace.span("repro.local_knn.device"):
+                    nbr, sims = fn(jnp.asarray(w), jnp.asarray(c),
+                                   jnp.asarray(mem), k)
+                    nbr = np.asarray(nbr)[: len(batch)]
+                    sims = np.asarray(sims)[: len(batch)]
+                # Scatter back per configuration (each user appears in
+                # exactly one cluster per configuration).
+                with trace.span("repro.local_knn.scatter"):
+                    for j, ci in enumerate(batch):
+                        cfg = plan.config_of[ci]
+                        users = plan.members[ci]
+                        out_ids[cfg, users] = nbr[j, : len(users)]
+                        out_sims[cfg, users] = sims[j, : len(users)]
+                trace.add("repro.local_knn.pairs_useful", useful)
+                trace.add("repro.local_knn.pairs_computed",
+                          m * int(cap) * (int(cap) - 1))
     return out_ids, out_sims

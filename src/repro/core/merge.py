@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.knn.topk import merge_topk
+from repro.sched import trace
 from repro.types import KNNGraph
 
 
@@ -28,5 +29,6 @@ def _merge(ids_tkn, sims_tkn, k: int):
 
 def merge_partial(ids: np.ndarray, sims: np.ndarray, k: int) -> KNNGraph:
     """ids/sims: [t, n, k'] per-configuration partial KNNs → final graph."""
-    out_ids, out_sims = _merge(jnp.asarray(ids), jnp.asarray(sims), k)
-    return KNNGraph(ids=np.asarray(out_ids), sims=np.asarray(out_sims))
+    with trace.span("repro.merge"):
+        out_ids, out_sims = _merge(jnp.asarray(ids), jnp.asarray(sims), k)
+        return KNNGraph(ids=np.asarray(out_ids), sims=np.asarray(out_sims))

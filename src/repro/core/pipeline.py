@@ -2,13 +2,12 @@
 
 Step 1 cluster (FastRandomHash + recursive split) → Step 2 per-cluster
 partial KNNs → Step 3 merge. Returns the approximate KNN graph plus a
-stats record (timings, similarity counts, cluster histogram) that the
-benchmarks consume.
+stats record (similarity counts, cluster histogram). Each step times
+itself through ``repro.sched.trace`` spans.
 """
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import numpy as np
 
@@ -22,17 +21,10 @@ from repro.types import Dataset, KNNGraph
 
 @dataclasses.dataclass
 class C2Stats:
-    t_cluster: float
-    t_local: float
-    t_merge: float
     n_clusters: int
     n_sims: int            # Σ |C|(|C|−1)/2 — Step 2 similarity budget
     max_cluster: int
     cluster_sizes: np.ndarray
-
-    @property
-    def total(self) -> float:
-        return self.t_cluster + self.t_local + self.t_merge
 
 
 def cluster_and_conquer(
@@ -42,23 +34,14 @@ def cluster_and_conquer(
 ) -> tuple[KNNGraph, C2Stats]:
     params = params or C2Params()
 
-    t0 = time.perf_counter()
     if gf is None:
         gf = fingerprint_dataset(ds, n_bits=params.n_bits, seed=params.seed)
     plan: ClusterPlan = build_plan(ds, params)
-    t1 = time.perf_counter()
-
     ids, sims = local_knn(plan, gf, params)
-    t2 = time.perf_counter()
-
     graph = merge_partial(ids, sims, params.k)
-    t3 = time.perf_counter()
 
     sizes = plan.sizes
     stats = C2Stats(
-        t_cluster=t1 - t0,
-        t_local=t2 - t1,
-        t_merge=t3 - t2,
         n_clusters=plan.n_clusters,
         n_sims=plan.brute_force_sims(),
         max_cluster=int(sizes.max()) if len(sizes) else 0,

@@ -58,6 +58,7 @@ from repro.core.merge import merge_partial
 from repro.core.params import C2Params
 from repro.core.splitting import split_config
 from repro.knn.greedy import reverse_neighbors_np
+from repro.sched import trace
 from repro.sketch.goldfinger import (GoldFinger, fingerprint_dataset,
                                      popcount_rows)
 from repro.types import NEG_INF, PAD_ID, Dataset, KNNGraph
@@ -842,29 +843,32 @@ def build_index(ds: Dataset, params: C2Params | None = None, *,
         ids, sims = local_knn(plan, gf, params)
         graph = merge_partial(ids, sims, params.k)
 
-    depth = params.split_depth
-    paths = np.full((plan.n_clusters, depth), NO_HASH, dtype=np.int32)
-    for ci, p in enumerate(plan.paths):
-        paths[ci, : len(p)] = p[:depth]
-    sizes = plan.sizes
-    offsets = np.zeros(plan.n_clusters + 1, dtype=np.int64)
-    np.cumsum(sizes, out=offsets[1:])
-    members = (np.concatenate(plan.members) if plan.members
-               else np.zeros((0,), np.int32)).astype(np.int32)
-
-    return KNNIndex(
-        graph_ids=np.ascontiguousarray(graph.ids, dtype=np.int32),
-        graph_sims=np.ascontiguousarray(graph.sims, dtype=np.float32),
-        words=np.asarray(gf.words, dtype=np.uint32),
-        card=np.asarray(gf.card, dtype=np.int32),
-        rev_ids=reverse_neighbors_np(np.asarray(graph.ids), r_max=graph.k),
-        hash_seeds=frh_seeds(params),
-        cluster_paths=paths,
-        cluster_config=plan.config_of.astype(np.int32),
-        cluster_members=members,
-        cluster_offsets=offsets,
-        b=params.b,
-        n_bits=gf.n_bits,
-        fp_seed=params.seed,
-        split_depth=depth,
-    )
+    with trace.span("repro.index"):
+        depth = params.split_depth
+        paths = np.full((plan.n_clusters, depth), NO_HASH, dtype=np.int32)
+        for ci, p in enumerate(plan.paths):
+            paths[ci, : len(p)] = p[:depth]
+        sizes = plan.sizes
+        offsets = np.zeros(plan.n_clusters + 1, dtype=np.int64)
+        np.cumsum(sizes, out=offsets[1:])
+        members = (np.concatenate(plan.members) if plan.members
+                   else np.zeros((0,), np.int32)).astype(np.int32)
+        with trace.span("repro.index.reverse"):
+            rev_ids = reverse_neighbors_np(np.asarray(graph.ids),
+                                           r_max=graph.k)
+        return KNNIndex(
+            graph_ids=np.ascontiguousarray(graph.ids, dtype=np.int32),
+            graph_sims=np.ascontiguousarray(graph.sims, dtype=np.float32),
+            words=np.asarray(gf.words, dtype=np.uint32),
+            card=np.asarray(gf.card, dtype=np.int32),
+            rev_ids=rev_ids,
+            hash_seeds=frh_seeds(params),
+            cluster_paths=paths,
+            cluster_config=plan.config_of.astype(np.int32),
+            cluster_members=members,
+            cluster_offsets=offsets,
+            b=params.b,
+            n_bits=gf.n_bits,
+            fp_seed=params.seed,
+            split_depth=depth,
+        )

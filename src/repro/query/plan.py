@@ -424,8 +424,9 @@ class DescentPlan:
         """
         hops = self.spec.hops if hops is None else hops
         if self.cache is None:
-            seeds = route(self.index, items, offsets,
-                          self.spec.seeds_per_config, placed=placed)
+            with trace.span("repro.wave.route"):
+                seeds = route(self.index, items, offsets,
+                              self.spec.seeds_per_config, placed=placed)
             return self.descend_rows(qgf.words, qgf.card, seeds, k,
                                      hops=hops)
         self.cache.sync()
@@ -445,8 +446,9 @@ class DescentPlan:
             m_items, m_offsets = _csr_subset(items, offsets, miss)
             m_placed = ([placed[i] for i in miss]
                         if placed is not None else None)
-            seeds = route(self.index, m_items, m_offsets,
-                          self.spec.seeds_per_config, placed=m_placed)
+            with trace.span("repro.wave.route"):
+                seeds = route(self.index, m_items, m_offsets,
+                              self.spec.seeds_per_config, placed=m_placed)
             m_ids, m_sims = self.descend_rows(qw[miss], qc[miss], seeds,
                                               k, hops=hops)
             degraded = self._degraded()
@@ -472,41 +474,44 @@ class DescentPlan:
         spec = self.spec
         beam = max(self.beam if beam is None else beam, k)
         hops = spec.hops if hops is None else hops
-        q_words = np.asarray(q_words)
-        q_card = np.asarray(q_card)
-        seeds = np.asarray(seeds)
-        qn = q_words.shape[0]
-        qcap = capacity_of(qn, minimum=8)
-        qw = np.zeros((qcap, q_words.shape[1]), dtype=np.uint32)
-        qw[:qn] = q_words
-        qcard = np.zeros(qcap, dtype=np.int32)
-        qcard[:qn] = q_card
-        qseeds = np.full((qcap, seeds.shape[1]), PAD_ID, dtype=np.int32)
-        qseeds[:qn] = seeds
-        if spec.placement > 1:
-            sd = self._sync_sharded()
-            ids, sims = sd.descend(
-                qw, qcard, qseeds, k=k, beam=beam, hops=hops,
-                kernel=spec.kernel, dma=spec.dma, tag=self.key)
-            self._note_stats(sd.last_hop_stats[:qn])
-        else:
-            graph_ids, rev_ids, words, card, tomb = self._sync_single()
-            ids, sims, stats = batched_descent(
-                graph_ids, rev_ids, words, card,
-                jnp.asarray(qw), jnp.asarray(qcard), jnp.asarray(qseeds),
-                k=k, beam=beam, hops=hops, kernel=spec.kernel,
-                dma=spec.dma, tag=self.key, tomb=tomb)
-            self._note_stats(np.asarray(stats)[:qn])
-        return np.asarray(ids)[:qn], np.asarray(sims)[:qn]
+        with trace.span("repro.wave.descent"):
+            q_words = np.asarray(q_words)
+            q_card = np.asarray(q_card)
+            seeds = np.asarray(seeds)
+            qn = q_words.shape[0]
+            qcap = capacity_of(qn, minimum=8)
+            qw = np.zeros((qcap, q_words.shape[1]), dtype=np.uint32)
+            qw[:qn] = q_words
+            qcard = np.zeros(qcap, dtype=np.int32)
+            qcard[:qn] = q_card
+            qseeds = np.full((qcap, seeds.shape[1]), PAD_ID, dtype=np.int32)
+            qseeds[:qn] = seeds
+            if spec.placement > 1:
+                sd = self._sync_sharded()
+                ids, sims = sd.descend(
+                    qw, qcard, qseeds, k=k, beam=beam, hops=hops,
+                    kernel=spec.kernel, dma=spec.dma, tag=self.key)
+                self._note_stats(sd.last_hop_stats[:qn])
+            else:
+                graph_ids, rev_ids, words, card, tomb = self._sync_single()
+                ids, sims, stats = batched_descent(
+                    graph_ids, rev_ids, words, card,
+                    jnp.asarray(qw), jnp.asarray(qcard), jnp.asarray(qseeds),
+                    k=k, beam=beam, hops=hops, kernel=spec.kernel,
+                    dma=spec.dma, tag=self.key, tomb=tomb)
+                self._note_stats(np.asarray(stats)[:qn])
+            return np.asarray(ids)[:qn], np.asarray(sims)[:qn]
 
     def query_batch(self, profiles, k: int | None = None,
                     hops: int | None = None):
         """Answer raw profiles: (ids int32[q, k], sims float32[q, k])."""
-        items, offsets = profiles_to_csr(profiles)
-        qgf = fingerprint_profiles(items, offsets, self.index.n_bits,
-                                   self.index.fp_seed)
-        return self.search(items, offsets, qgf, k or self.spec.k,
-                           hops=hops)
+        with trace.span("repro.wave"):
+            with trace.span("repro.wave.fingerprint"):
+                items, offsets = profiles_to_csr(profiles)
+                qgf = fingerprint_profiles(items, offsets, self.index.n_bits,
+                                           self.index.fp_seed)
+            return self.search(items, offsets, qgf, k or self.spec.k,
+                               hops=hops)
 
     # -- the serving loop --------------------------------------------------
 

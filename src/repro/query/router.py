@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.core import hashing
 from repro.query.index import KNNIndex
+from repro.sched import trace
 from repro.sketch.goldfinger import GoldFinger, fingerprint_dataset
 from repro.types import PAD_ID, Dataset
 
@@ -131,4 +132,7 @@ def route(index: KNNIndex, items: np.ndarray, offsets: np.ndarray,
                                num=min(cap, len(alive)), dtype=np.int64)
             fill = alive[take].astype(np.int32)
             out[qi, : len(fill)] = fill
+    if trace.active():
+        trace.add("repro.wave.seeds", int(np.count_nonzero(out != PAD_ID)))
+        trace.add("repro.wave.seed_slots", out.size)
     return out

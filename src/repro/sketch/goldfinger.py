@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.hashing import fmix32
+from repro.sched import trace
 from repro.types import Dataset
 
 DEFAULT_BITS = 1024
@@ -54,15 +55,17 @@ def item_bit_positions(items: np.ndarray, n_bits: int, seed: int) -> np.ndarray:
 def fingerprint_dataset(ds: Dataset, n_bits: int = DEFAULT_BITS, seed: int = 0) -> GoldFinger:
     """Build GoldFinger fingerprints for every user of ``ds`` (host-side)."""
     assert n_bits % 32 == 0, "n_bits must be a multiple of 32"
-    W = n_bits // 32
-    pos = item_bit_positions(ds.items, n_bits, seed)
-    word_idx = (pos // 32).astype(np.int64)
-    bit = np.uint32(1) << (pos % 32).astype(np.uint32)
-    words = np.zeros((ds.n_users, W), dtype=np.uint32)
-    # Scatter-OR each item's bit into its user's row.
-    user_of = np.repeat(np.arange(ds.n_users, dtype=np.int64), ds.profile_sizes)
-    np.bitwise_or.at(words, (user_of, word_idx), bit)
-    card = popcount_rows(words)
+    with trace.span("repro.fingerprint"):
+        W = n_bits // 32
+        pos = item_bit_positions(ds.items, n_bits, seed)
+        word_idx = (pos // 32).astype(np.int64)
+        bit = np.uint32(1) << (pos % 32).astype(np.uint32)
+        words = np.zeros((ds.n_users, W), dtype=np.uint32)
+        # Scatter-OR each item's bit into its user's row.
+        user_of = np.repeat(np.arange(ds.n_users, dtype=np.int64),
+                            ds.profile_sizes)
+        np.bitwise_or.at(words, (user_of, word_idx), bit)
+        card = popcount_rows(words)
     return GoldFinger(words=words, card=card)
 
 
