@@ -2,9 +2,10 @@
 
 Produces a :class:`ClusterPlan` — a *static* description of every cluster
 (member lists, sizes, originating hash configuration) that downstream steps
-(local KNN, distributed shard_map scheduling) consume. Hash values are
-computed vectorized; the recursive split is host-side bookkeeping
-(DESIGN.md §3).
+(local KNN, distributed shard_map scheduling) consume. The table of
+each user's smallest distinct hash values is one device program
+(``hashing.user_distinct_hashes_device``); the recursive split is
+host-side bookkeeping over it (``core/splitting.py``).
 """
 from __future__ import annotations
 
@@ -56,9 +57,8 @@ def build_plan(ds: Dataset, params: C2Params) -> ClusterPlan:
     with trace.span("repro.cluster"):
         seeds = frh_seeds(params)
         with trace.span("repro.cluster.hash"):
-            item_h = hashing.item_hashes(ds.items, seeds, params.b)  # [t,nnz]
-            cands = hashing.user_distinct_hashes_np(item_h, ds.offsets,
-                                                    params.split_depth)
+            cands = hashing.user_distinct_hashes_device(
+                ds.items, ds.offsets, seeds, params.b, params.split_depth)
         members: list[np.ndarray] = []
         config_of: list[int] = []
         paths: list[tuple[int, ...]] = []

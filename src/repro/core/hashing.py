@@ -12,13 +12,23 @@ which vectorizes over both numpy and jnp uint32 arrays.
 Splitting support: ``H\\η(u) = min_{item ∈ P_u, h(item) > η} h(item)`` is what
 recursive splitting (§II-D) evaluates; we expose per-user *sorted distinct
 hash values* so the split planner can walk down each user's candidate
-sequence without rehashing (DESIGN.md §3).
+sequence without rehashing (``core/splitting.py``). Two paths build that
+table and share only the hash: :func:`user_distinct_hashes_device`, one
+jitted program over a whole dataset (the build's cluster plan), and
+:func:`user_distinct_hashes_np` for the small, variable-shape tables of
+query routing and insert cohorts. ``PERF.md`` §3 names the host counters
+that say which path each caller takes.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+
+from repro.sched import trace
 
 NO_HASH = np.int32(2**31 - 1)  # "H undefined" sentinel (empty masked min)
 
@@ -40,7 +50,7 @@ def item_hashes(items, seeds, b: int):
 
     ``items``: int32[nnz]; ``seeds``: int32[t]. numpy in → numpy out,
     jnp in → jnp out (the device path is used by the fused Pallas kernel's
-    reference and by distributed hashing).
+    reference, by distributed hashing and by :func:`distinct_hash_table`).
     """
     is_np = isinstance(items, np.ndarray)
     xp = np if is_np else jnp
@@ -94,15 +104,16 @@ def user_distinct_hashes_np(item_h: np.ndarray, offsets: np.ndarray,
 
     Recursive splitting only ever moves a user to its next distinct hash
     value above the current cluster index, so this table fully determines
-    every split decision (DESIGN.md §3).
+    every split decision (``core/splitting.py``).
 
-    Implementation (§Perf C² iteration 2): ``depth`` passes of masked
-    ``minimum.reduceat`` — O(depth·nnz) with no sort. The previous
-    lexsort formulation (kept below as the test oracle) was 68% of C²'s
-    end-to-end wall time on the ml10M benchmark.
+    Host path, for routing and insert cohorts (a whole dataset takes
+    :func:`user_distinct_hashes_device`): ``depth`` passes of masked
+    ``minimum.reduceat`` — O(depth·nnz) with no sort. The lexsort
+    formulation kept below is the test oracle.
     """
     t, nnz = item_h.shape
     n = len(offsets) - 1
+    trace.add("repro.hash.host_users", n)
     out = np.full((t, n, depth), NO_HASH, dtype=np.int32)
     sizes = np.diff(offsets)
     nonempty = sizes > 0
@@ -149,3 +160,84 @@ def user_distinct_hashes_np_ref(item_h: np.ndarray, offsets: np.ndarray,
         sel = rank < depth
         out[i, du[sel], rank[sel]] = dh[sel]
     return out
+
+
+def packs(n_users: int, b: int) -> bool:
+    """Whether ``user · (b + 1) + v`` fits int32 for every user and every
+    ``v`` in ``[0, b]``: the device table's segmented maxima are then plain
+    cumulative maxima of that one key. True for every Table I dataset at
+    b = 4096 (ml20M: 5.7e8)."""
+    return n_users * (b + 1) < 2**31
+
+
+def _segment_max(low, user, n_users: int, b: int, reverse: bool):
+    """Running max of ``low`` (int32[t, nnz], values in [0, b]) within each
+    user's segment, forward or backward. ``user``: int32[nnz], the
+    nondecreasing user id of each element."""
+    if packs(n_users, b):
+        # A later segment's key beats every value of an earlier one.
+        key = ((n_users - 1 - user) if reverse else user) * (b + 1)
+        return lax.cummax(key[None, :] + low, axis=1, reverse=reverse) - key
+    # The scan's first element of each segment restarts the maximum.
+    nxt = jnp.roll(user, 1 if not reverse else -1)
+    restart = jnp.broadcast_to(user != nxt, low.shape)
+    restart = restart.at[:, -1 if reverse else 0].set(True)
+
+    def combine(a, c):
+        return a[0] | c[0], jnp.where(c[0], c[1], jnp.maximum(a[1], c[1]))
+
+    return lax.associative_scan(combine, (restart, low), reverse=reverse,
+                                axis=1)[1]
+
+
+@functools.partial(jax.jit, static_argnames=("b", "depth"))
+def distinct_hash_table(items, offsets, seeds, *, b: int, depth: int):
+    """Device program behind :func:`user_distinct_hashes_device`.
+
+    ``items``: int32[nnz] (nnz ≥ 1); ``offsets``: int32[n + 1]; ``seeds``:
+    int32[t] → int32[t, n, depth]. Works on ``low = b − h``, so a user's
+    smallest remaining hash is its segment's largest ``low`` and a masked
+    element reads 0. Each of the ``depth`` levels takes every user's
+    segment maximum (forward and backward running maxima), writes it as
+    the level's column, and masks every element equal to it: the numpy
+    path's passes, with segmented scans in place of ``reduceat``.
+    """
+    n = offsets.shape[0] - 1
+    nnz = items.shape[0]
+    low0 = b - item_hashes(items, seeds, b)            # [t, nnz] in [1, b]
+    user = jnp.cumsum(jnp.zeros(nnz, jnp.int32)
+                      .at[offsets[1:-1]].add(1, mode="drop"))
+    first = jnp.minimum(offsets[:-1], nnz - 1)
+    nonempty = offsets[1:] > offsets[:-1]
+
+    def level(d, carry):
+        low, table = carry
+        seg_max = jnp.maximum(_segment_max(low, user, n, b, False),
+                              _segment_max(low, user, n, b, True))
+        head = seg_max[:, first]
+        col = jnp.where(nonempty & (head > 0), b - head, NO_HASH)
+        table = lax.dynamic_update_index_in_dim(table, col, d, axis=2)
+        return jnp.where(low == seg_max, 0, low), table
+
+    table = jnp.full((seeds.shape[0], n, depth), NO_HASH, jnp.int32)
+    return lax.fori_loop(0, depth, level, (low0, table))[1]
+
+
+def user_distinct_hashes_device(items: np.ndarray, offsets: np.ndarray,
+                                seeds: np.ndarray, b: int,
+                                depth: int) -> np.ndarray:
+    """:func:`user_distinct_hashes_np` of ``item_hashes(items, seeds, b)``,
+    bit for bit, computed on the device: int32[t, n, depth] on the host.
+
+    One upload of the CSR arrays, one jitted program (compiled once per
+    dataset shape), one readback. For whole datasets: the build's cluster
+    plan (``core/clustering.build_plan``).
+    """
+    n = len(offsets) - 1
+    trace.add("repro.hash.device_users", n)
+    if len(items) == 0:
+        return np.full((len(seeds), n, depth), NO_HASH, dtype=np.int32)
+    table = distinct_hash_table(
+        jnp.asarray(items, jnp.int32), jnp.asarray(offsets, jnp.int32),
+        jnp.asarray(seeds, jnp.int32), b=b, depth=depth)
+    return np.asarray(table)

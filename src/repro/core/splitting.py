@@ -42,7 +42,8 @@ def split_config(cands: np.ndarray, max_cluster: int) -> SplitResult:
     """Split one configuration.
 
     cands: int32[n_users, depth] — ascending distinct item-hash values per
-    user (NO_HASH padded), from ``user_distinct_hashes_np``.
+    user (NO_HASH padded), from ``user_distinct_hashes_device`` (a build)
+    or ``user_distinct_hashes_np`` (insert cohorts).
     """
     n, depth = cands.shape
     valid = cands[:, 0] != NO_HASH  # users with non-empty profiles

@@ -4,9 +4,11 @@ The main path's kernels and jitted programs are compiled for one chip of
 a ``v5e:2x2`` topology at the shapes ``chip_smoke.py`` runs: the
 MovieLens-10M index (69,816 users, padded by the serving plan to
 131,072 rows), 1024-bit GoldFinger sketches (W = 32 words), k = 30
-forward and reverse neighbors, a 256-query wave with beam 32, and the
-build's 2048-row capacity groups. A compile that passes here is not a
-chip run; what the chip's compiler refuses fails here for free.
+forward and reverse neighbors, a 256-query wave with beam 32, the
+build's 2048-row capacity groups, and the build's FRH distinct-hash
+table (t = 8, b = 4096, depth 6) over ml10M's 5.9 M items. A compile
+that passes here is not a chip run; what the chip's compiler refuses
+fails here for free.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library.
@@ -31,6 +33,7 @@ Q = 256               # one wave
 SEEDS = 128           # 8 hash configurations x 16 routed seeds
 CAP, M_GROUP = 2048, 16   # one capacity group of the build
 N_PROFILES, P_MAX = 70_144, 1348  # ml10M users padded to 256; widest profile
+N_USERS, NNZ = 69_816, 5_885_489  # ml10M users and their items, unpadded
 
 
 @pytest.fixture(scope="module")
@@ -120,6 +123,16 @@ def test_minhash_kernel_compiles_at_ml10m_profile_width(one_chip):
         seeds=tuple(range(1, 9)), b=4096, block_n=256,
         interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_distinct_hash_table_compiles_at_ml10m_shapes(one_chip):
+    from repro.core.hashing import distinct_hash_table
+
+    compiled = distinct_hash_table.lower(
+        _shape((NNZ,), jnp.int32, one_chip),
+        _shape((N_USERS + 1,), jnp.int32, one_chip),
+        _shape((8,), jnp.int32, one_chip), b=4096, depth=6).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
 
 
 def test_jnp_wave_program_compiles_at_smoke_shapes(one_chip):

@@ -103,3 +103,65 @@ def test_theorem1_collision_probability(shared, only1, only2):
     # 3σ slack for the empirical estimate over n_seeds draws.
     sigma = 3 * np.sqrt(max(hits * (1 - hits), 0.25 / n_seeds) / n_seeds)
     assert lo - sigma <= hits <= hi + sigma
+
+
+def _csr(seed, n, max_size, n_items):
+    """Seeded CSR profiles with empty users, single-item users and items
+    repeated inside a profile (drawn with replacement)."""
+    rng = np.random.default_rng(seed)
+    sizes = rng.integers(2, max(max_size, 2) + 1, size=n)
+    sizes[rng.random(n) < 0.15] = 1
+    sizes[(rng.random(n) < 0.15) | (max_size == 0)] = 0
+    offsets = np.zeros(n + 1, dtype=np.int64)
+    np.cumsum(sizes, out=offsets[1:])
+    items = rng.integers(0, n_items, size=int(offsets[-1])).astype(np.int32)
+    return items, offsets
+
+
+# (seed, users, widest profile, item universe, b, depth, one packed key)
+DEVICE_CASES = {
+    "ties_b8_depth6": (1, 120, 40, 300, 8, 6, True),
+    "ties_b8_depth1": (2, 120, 40, 300, 8, 1, True),
+    "b4096_depth6": (3, 400, 60, 5000, 4096, 6, True),
+    "b64_few_distinct": (4, 200, 4, 50, 64, 6, True),
+    "two_key_scan": (5, 256, 30, 10**6, 2**23, 6, False),
+    "all_empty": (6, 5, 0, 10, 64, 6, True),
+}
+
+
+@pytest.mark.parametrize("case", DEVICE_CASES.values(), ids=DEVICE_CASES)
+def test_device_table_matches_host_and_oracle(case):
+    seed, n, max_size, n_items, b, depth, packed = case
+    items, offsets = _csr(seed, n, max_size, n_items)
+    seeds = np.arange(3, dtype=np.int32) + np.int32(seed * 1009)
+    assert hashing.packs(n, b) == packed  # which scan the shapes choose
+    item_h = hashing.item_hashes(items, seeds, b)
+    host = hashing.user_distinct_hashes_np(item_h, offsets, depth)
+    oracle = hashing.user_distinct_hashes_np_ref(item_h, offsets, depth)
+    dev = hashing.user_distinct_hashes_device(items, offsets, seeds, b, depth)
+    assert dev.dtype == np.int32 and dev.shape == (3, n, depth)
+    np.testing.assert_array_equal(dev, host)
+    np.testing.assert_array_equal(dev, oracle)
+
+
+def test_build_plan_matches_the_host_table_plan(monkeypatch):
+    from repro.core.clustering import build_plan
+    from repro.core.params import C2Params
+    from repro.data.synthetic import make_dataset
+
+    ds = make_dataset("synth", scale=0.2, seed=11)
+    p = C2Params(k=10, b=64, t=4, max_cluster=40)
+    dev = build_plan(ds, p)
+
+    def host_table(items, offsets, seeds, b, depth):
+        return hashing.user_distinct_hashes_np(
+            hashing.item_hashes(items, seeds, b), offsets, depth)
+
+    monkeypatch.setattr(hashing, "user_distinct_hashes_device", host_table)
+    host = build_plan(ds, p)
+    assert dev.n_clusters == host.n_clusters > 0
+    assert any(len(path) > 1 for path in dev.paths)  # splits happened
+    for a, b in zip(dev.members, host.members):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(dev.config_of, host.config_of)
+    assert dev.paths == host.paths

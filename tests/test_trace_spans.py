@@ -200,3 +200,26 @@ def test_a_wave_emits_one_wave_span_with_its_three_children(engine, tracing):
     c = trace.summary()["counters"]
     assert c["repro.wave.seed_slots"] == len(profiles) * 8 * 16
     assert 0 < c["repro.wave.seeds"] <= c["repro.wave.seed_slots"]
+
+
+def test_the_build_tables_users_on_the_device(small_ds, tracing):
+    cluster_and_conquer(small_ds, C2Params(k=10, b=256, t=4,
+                                           max_cluster=120, n_bits=512))
+    c = trace.summary()["counters"]
+    assert c["repro.hash.device_users"] == small_ds.n_users
+    assert "repro.hash.host_users" not in c
+
+
+def test_a_wave_tables_its_queries_on_the_host(engine, tracing):
+    from repro.query.router import profiles_to_csr, query_hash_tables
+
+    eng, profiles = engine
+    eng.query_batch(profiles)
+    c = trace.summary()["counters"]
+    assert c["repro.hash.host_users"] == len(profiles)
+    assert "repro.hash.device_users" not in c
+    items, offsets = profiles_to_csr(profiles[:5])
+    query_hash_tables(eng.index, items, offsets)
+    c = trace.summary()["counters"]
+    assert c["repro.hash.host_users"] == len(profiles) + 5
+    assert "repro.hash.device_users" not in c
