@@ -58,7 +58,7 @@ from repro.query.index import build_index  # noqa: E402
 from repro.query.router import routed_queries  # noqa: E402
 from repro.query.search import exact_knn  # noqa: E402
 from repro.query.sharded import ShardedDescent  # noqa: E402
-from repro.sketch.goldfinger import fingerprint_dataset  # noqa: E402
+from repro.sketch.goldfinger import sketch_dataset  # noqa: E402
 
 DATASET = "ml10M"
 SEED = 0
@@ -95,7 +95,7 @@ def build():
     ds = make_dataset(DATASET, scale=1.0, seed=SEED)
     t1 = time.perf_counter()
     params = params_for(DATASET)
-    gf = fingerprint_dataset(ds, n_bits=params.n_bits, seed=params.seed)
+    gf = sketch_dataset(ds, params)
     plan = build_plan(ds, params)
     t2 = time.perf_counter()
     index = build_index(ds, params, gf=gf, plan=plan)

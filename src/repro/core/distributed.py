@@ -142,10 +142,10 @@ def distributed_c2(ds, params: C2Params, mesh, gf: GoldFinger | None = None,
     """Full distributed pipeline: host plan → mesh Step 2 → merge."""
     from repro.core.clustering import build_plan
     from repro.core.merge import merge_partial
-    from repro.sketch.goldfinger import fingerprint_dataset
+    from repro.sketch.goldfinger import sketch_dataset
 
     if gf is None:
-        gf = fingerprint_dataset(ds, n_bits=params.n_bits, seed=params.seed)
+        gf = sketch_dataset(ds, params)
     plan = build_plan(ds, params)
     ids, sims, dp = distributed_local_knn(plan, gf, params, mesh, data_axis)
     graph = merge_partial(ids, sims, params.k)

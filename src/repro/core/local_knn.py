@@ -4,10 +4,13 @@ The paper hands each cluster to a thread and switches between brute force
 (|C| < ρk²) and Hyrec. The TPU-native version batches clusters of similar
 size into padded capacity groups and vmaps one fused similarity+top-k over
 each group — every cluster in a group is processed by the same program, so
-there is no divergence and no synchronization (DESIGN.md §3).
+there is no divergence and no synchronization.
 
 Capacity groups are powers of two ≥ 32, so padding waste is < 2× and each
-group compiles once per (capacity, k).
+group compiles once per (capacity, k). The group program scores GoldFinger
+sketches (W = 32 words at 1,024 bits) with the VPU popcount and incidence
+rows (W = |I|/32, 328 words for MovieLens-10M) as an int8 bit-plane matmul
+on the MXU; PERF.md §3 lists the spans and counters each metric reads.
 """
 from __future__ import annotations
 
@@ -163,4 +166,5 @@ def local_knn(plan: ClusterPlan, gf: GoldFinger, params: C2Params):
                 trace.add("repro.local_knn.pairs_useful", useful)
                 trace.add("repro.local_knn.pairs_computed",
                           m * int(cap) * (int(cap) - 1))
+                trace.add("repro.local_knn.rows_gathered", m * int(cap))
     return out_ids, out_sims

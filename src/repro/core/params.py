@@ -14,7 +14,11 @@ class C2Params:
     n_bits: int = 1024         # GoldFinger width (paper experiments: 1024)
     seed: int = 0
     split_depth: int = 6       # precomputed distinct-hash depth for splitting
-    use_goldfinger: bool = True  # Table V ablation: False → exact Jaccard
+    # True: score GoldFinger sketches of n_bits bits. False: score incidence
+    # rows of the whole item universe (Table V's raw data), so every sim is
+    # the exact Jaccard; n_bits is then unused. Build and index follow it
+    # (sketch.goldfinger.sketch_dataset), and so do the index's queries.
+    use_goldfinger: bool = True
     use_pallas: bool = False   # route local brute force through the kernel
 
     @property

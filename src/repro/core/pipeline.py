@@ -15,7 +15,7 @@ from repro.core.clustering import ClusterPlan, build_plan
 from repro.core.local_knn import local_knn
 from repro.core.merge import merge_partial
 from repro.core.params import C2Params
-from repro.sketch.goldfinger import GoldFinger, fingerprint_dataset
+from repro.sketch.goldfinger import GoldFinger, sketch_dataset
 from repro.types import Dataset, KNNGraph
 
 
@@ -35,7 +35,7 @@ def cluster_and_conquer(
     params = params or C2Params()
 
     if gf is None:
-        gf = fingerprint_dataset(ds, n_bits=params.n_bits, seed=params.seed)
+        gf = sketch_dataset(ds, params)
     plan: ClusterPlan = build_plan(ds, params)
     ids, sims = local_knn(plan, gf, params)
     graph = merge_partial(ids, sims, params.k)

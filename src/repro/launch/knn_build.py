@@ -22,14 +22,14 @@ from repro.core.local_knn import local_knn
 from repro.core.merge import merge_partial
 from repro.core.params import C2Params, params_for
 from repro.data.synthetic import make_dataset
-from repro.sketch.goldfinger import fingerprint_dataset
+from repro.sketch.goldfinger import sketch_dataset
 from repro.types import NEG_INF, PAD_ID
 
 
 def build(ds, params: C2Params, ckpt_dir: str | None = None,
           mesh=None, verbose: bool = True, gf=None):
     if gf is None:
-        gf = fingerprint_dataset(ds, n_bits=params.n_bits, seed=params.seed)
+        gf = sketch_dataset(ds, params)
     plan = build_plan(ds, params)
     t, n, k = params.t, ds.n_users, params.k
     ids = np.full((t, n, k), PAD_ID, dtype=np.int32)
@@ -101,7 +101,7 @@ def main(argv=None):
               f"{args.fail_after_config} configs")
         raise SystemExit(42)
     t0 = time.time()
-    gf = fingerprint_dataset(ds, n_bits=params.n_bits, seed=params.seed)
+    gf = sketch_dataset(ds, params)
     graph, plan = build(ds, params, ckpt_dir=args.ckpt_dir, gf=gf)
     print(f"[knn] built KNN graph for {ds.n_users} users in "
           f"{time.time() - t0:.2f}s "

@@ -112,7 +112,7 @@ class LifecycleManager:
         ix = self.engine.index
         cfg = self.cfg
         items, offsets = profiles_to_csr([profile])
-        qgf = fingerprint_profiles(items, offsets, ix.n_bits, ix.fp_seed)
+        qgf = fingerprint_profiles(items, offsets, ix)
         before = self._ring(u)
         ix.swap_profile(u, np.asarray(qgf.words)[0], int(qgf.card[0]))
         seeds = self._neighborhood_seeds([u])

@@ -334,7 +334,7 @@ class QueryEngine:
         """
         ix = self.index
         items, offsets = profiles_to_csr([profile])
-        qgf = fingerprint_profiles(items, offsets, ix.n_bits, ix.fp_seed)
+        qgf = fingerprint_profiles(items, offsets, ix)
         placed = placements(ix, items, offsets)
         ids, sims = self.plan.search(items, offsets, qgf, ix.k,
                                      placed=placed)
@@ -435,8 +435,7 @@ class QueryEngine:
         total = 0.0
         for k, group in sorted(by_k.items()):
             items, offsets = profiles_to_csr([r.profile for r in group])
-            qgf = fingerprint_profiles(items, offsets, self.index.n_bits,
-                                       self.index.fp_seed)
+            qgf = fingerprint_profiles(items, offsets, self.index)
             exact_ids, _ = exact_knn(self.index.words, self.index.card,
                                      np.asarray(qgf.words),
                                      np.asarray(qgf.card), k,

@@ -3,7 +3,9 @@
 A :class:`KNNIndex` bundles everything the online query path needs:
 
 * the merged C² :class:`~repro.types.KNNGraph` (forward adjacency),
-* the GoldFinger fingerprints of every indexed user (similarity scoring),
+* the fingerprints of every indexed user (similarity scoring): GoldFinger
+  sketches, or incidence rows of the item universe for an exact-Jaccard
+  build (``sketch``; queries are fingerprinted the same way),
 * the FastRandomHash routing tables — per-configuration hash seeds plus
   the split-path → cluster-members mapping of the build-time
   :class:`~repro.core.clustering.ClusterPlan` — so an unseen profile can
@@ -59,15 +61,16 @@ from repro.core.params import C2Params
 from repro.core.splitting import split_config
 from repro.knn.greedy import reverse_neighbors_np
 from repro.sched import trace
-from repro.sketch.goldfinger import (GoldFinger, fingerprint_dataset,
-                                     popcount_rows)
+from repro.sketch.goldfinger import (GOLDFINGER, INCIDENCE, SKETCHES,
+                                     GoldFinger, popcount_rows,
+                                     sketch_dataset)
 from repro.types import NEG_INF, PAD_ID, Dataset, KNNGraph
 
 _ROWS = ("graph_ids", "graph_sims", "words", "card", "rev_ids",
          "tombstone", "last_touch")
 _TABLES = ("hash_seeds", "cluster_paths", "cluster_config",
            "cluster_members", "cluster_offsets")
-_META = ("b", "n_bits", "fp_seed", "split_depth", "version")
+_META = ("b", "n_bits", "fp_seed", "split_depth", "version", "sketch")
 
 _ROW_DTYPES = {"graph_ids": np.int32, "graph_sims": np.float32,
                "words": np.uint32, "card": np.int32, "rev_ids": np.int32,
@@ -100,7 +103,8 @@ class KNNIndex:
     def __init__(self, *, graph_ids, graph_sims, words, card, rev_ids,
                  hash_seeds, cluster_paths, cluster_config, cluster_members,
                  cluster_offsets, b, n_bits, fp_seed, split_depth,
-                 version: int = 0, tombstone=None, last_touch=None):
+                 version: int = 0, tombstone=None, last_touch=None,
+                 sketch: str = GOLDFINGER):
         self._n = int(np.asarray(graph_ids).shape[0])
         self._bufs: dict[str, np.ndarray] = {}
         row_args = {"graph_ids": graph_ids, "graph_sims": graph_sims,
@@ -126,6 +130,9 @@ class KNNIndex:
         self.n_bits = int(n_bits)
         self.fp_seed = int(fp_seed)
         self.split_depth = int(split_depth)
+        if sketch not in SKETCHES:
+            raise ValueError(f"unknown sketch {sketch!r}; known: {SKETCHES}")
+        self.sketch = str(sketch)  # how rows (and queries) are fingerprinted
         self.version = int(version)  # bumped on mutation (engine cache key)
         self._lut: dict | None = None
         # Optional write-ahead log (repro/faults/wal.py). When attached,
@@ -237,7 +244,8 @@ class KNNIndex:
 
     @property
     def gf(self) -> GoldFinger:
-        return GoldFinger(words=self.words, card=self.card)
+        return GoldFinger(words=self.words, card=self.card,
+                          sketch=self.sketch)
 
     @property
     def graph(self) -> KNNGraph:
@@ -349,6 +357,7 @@ class KNNIndex:
         be a previously removed user's row (its liveness flip rides the
         deletion journal so synced device masks follow).
         """
+        self._check_storable(words_row, card_row)
         if self._wal is not None:
             self._wal.record("append_user", words_row=words_row,
                              card_row=card_row, nbr_ids=nbr_ids,
@@ -464,6 +473,20 @@ class KNNIndex:
 
     # -- lifecycle mutations (repro/lifecycle drives these) ----------------
 
+    def _check_storable(self, words_row, card_row: int):
+        """An incidence row stores exact Jaccard only if every item of the
+        profile has its bit: refuse a profile with items past the index's
+        universe (its ``card`` counts them, its bits cannot), since two
+        stored rows sharing such an item would undercount their
+        intersection."""
+        if self.sketch != INCIDENCE:
+            return
+        bits = int(popcount_rows(np.asarray(words_row, np.uint32)[None])[0])
+        if bits != int(card_row):
+            raise ValueError(
+                f"profile has {int(card_row) - bits} item(s) at or past the "
+                f"incidence index's universe of {self.n_bits} items")
+
     def _check_live(self, u: int) -> int:
         u = int(u)
         if not 0 <= u < self._n:
@@ -558,6 +581,7 @@ class KNNIndex:
         (fed by a localized neighbors-of-neighbors descent) to move
         ``u``'s forward edges to its new neighborhood.
         """
+        self._check_storable(words_row, card_row)
         if self._wal is not None:
             self._wal.record("swap_profile", u=int(u), words_row=words_row,
                              card_row=card_row)
@@ -806,7 +830,7 @@ class KNNIndex:
     def save(self, path: str | Path):
         self.consolidate()
         arrays = {name: getattr(self, name) for name in _ROWS + _TABLES}
-        meta = {name: np.int64(getattr(self, name)) for name in _META}
+        meta = {name: np.asarray(getattr(self, name)) for name in _META}
         path = Path(path)
         path.parent.mkdir(parents=True, exist_ok=True)
         np.savez(path, **arrays, **meta, **self._journal_arrays())
@@ -816,7 +840,8 @@ class KNNIndex:
         z = np.load(path)
         kw = {name: z[name] for name in z.files
               if name not in _META and not name.startswith("jrn_")}
-        kw.update({name: int(z[name]) for name in _META})
+        # An index saved before ``sketch`` was recorded is GoldFinger.
+        kw.update({name: z[name].item() for name in _META if name in z.files})
         ix = cls(**kw)
         if "jrn_row_base" in z.files:  # pre-journal artifacts load fine
             ix._restore_journals(z)
@@ -835,7 +860,7 @@ def build_index(ds: Dataset, params: C2Params | None = None, *,
     """
     params = params or C2Params()
     if gf is None:
-        gf = fingerprint_dataset(ds, n_bits=params.n_bits, seed=params.seed)
+        gf = sketch_dataset(ds, params)
     if plan is None:
         plan = build_plan(ds, params)
     assert plan.paths is not None, "plan must retain split paths for routing"
@@ -871,4 +896,5 @@ def build_index(ds: Dataset, params: C2Params | None = None, *,
             n_bits=gf.n_bits,
             fp_seed=params.seed,
             split_depth=depth,
+            sketch=gf.sketch,
         )

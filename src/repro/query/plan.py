@@ -422,6 +422,10 @@ class DescentPlan:
         cache flushes on any journal-visible index mutation) and only
         the misses route + descend.
         """
+        if qgf.sketch != self.index.sketch:
+            raise ValueError(f"queries fingerprinted as {qgf.sketch!r} "
+                             f"against an index of {self.index.sketch!r} "
+                             f"rows")
         hops = self.spec.hops if hops is None else hops
         if self.cache is None:
             with trace.span("repro.wave.route"):
@@ -508,8 +512,7 @@ class DescentPlan:
         with trace.span("repro.wave"):
             with trace.span("repro.wave.fingerprint"):
                 items, offsets = profiles_to_csr(profiles)
-                qgf = fingerprint_profiles(items, offsets, self.index.n_bits,
-                                           self.index.fp_seed)
+                qgf = fingerprint_profiles(items, offsets, self.index)
             return self.search(items, offsets, qgf, k or self.spec.k,
                                hops=hops)
 
@@ -639,8 +642,7 @@ class DescentPlan:
         """
         spec = self.spec
         items, offsets = profiles_to_csr([r.profile for _, r in admitted])
-        qgf = fingerprint_profiles(items, offsets, self.index.n_bits,
-                                   self.index.fp_seed)
+        qgf = fingerprint_profiles(items, offsets, self.index)
         qw, qc = np.asarray(qgf.words), np.asarray(qgf.card)
         n_hit = 0
         if self.cache is None:

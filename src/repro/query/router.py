@@ -17,7 +17,9 @@ import numpy as np
 from repro.core import hashing
 from repro.query.index import KNNIndex
 from repro.sched import trace
-from repro.sketch.goldfinger import GoldFinger, fingerprint_dataset
+from repro.sketch.goldfinger import (INCIDENCE, GoldFinger,
+                                     fingerprint_dataset,
+                                     incidence_fingerprint)
 from repro.types import PAD_ID, Dataset
 
 
@@ -33,12 +35,16 @@ def profiles_to_csr(profiles) -> tuple[np.ndarray, np.ndarray]:
 
 
 def fingerprint_profiles(items: np.ndarray, offsets: np.ndarray,
-                         n_bits: int, seed: int) -> GoldFinger:
-    """GoldFinger fingerprints for query profiles (same hash as the build)."""
+                         index: KNNIndex) -> GoldFinger:
+    """Fingerprints for query profiles, made as ``index``'s rows were:
+    incidence rows at the index's width, or GoldFinger with the build's
+    ``n_bits`` and hash seed."""
     n_items = int(items.max()) + 1 if len(items) else 1
     ds = Dataset(name="queries", n_users=len(offsets) - 1, n_items=n_items,
                  items=items, offsets=offsets)
-    return fingerprint_dataset(ds, n_bits=n_bits, seed=seed)
+    if index.sketch == INCIDENCE:
+        return incidence_fingerprint(ds, n_bits=index.n_bits)
+    return fingerprint_dataset(ds, n_bits=index.n_bits, seed=index.fp_seed)
 
 
 def routed_queries(index: KNNIndex, profiles,
@@ -52,7 +58,7 @@ def routed_queries(index: KNNIndex, profiles,
     directly.
     """
     items, offsets = profiles_to_csr(profiles)
-    qgf = fingerprint_profiles(items, offsets, index.n_bits, index.fp_seed)
+    qgf = fingerprint_profiles(items, offsets, index)
     seeds = route(index, items, offsets, seeds_per_config)
     return np.asarray(qgf.words), np.asarray(qgf.card), seeds
 

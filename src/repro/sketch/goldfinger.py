@@ -11,6 +11,11 @@ from the fingerprints as::
 where ``card_u = popcount(fp_u)`` is precomputed once per user. Keeping the
 union in terms of precomputed cardinalities is what lets the TPU kernel turn
 the intersection into a single matmul (see kernels/goldfinger_knn).
+
+The same layout holds the paper's raw-data mode (Table V): one bit per item
+of the universe (:func:`incidence_fingerprint`), whose popcount Jaccard is
+the exact set Jaccard. :func:`sketch_dataset` makes whichever of the two a
+build's :class:`~repro.core.params.C2Params` asks for.
 """
 from __future__ import annotations
 
@@ -26,13 +31,20 @@ from repro.types import Dataset
 
 DEFAULT_BITS = 1024
 
+# Sketch kinds: hashed GoldFinger bits, or one bit per item (exact Jaccard).
+GOLDFINGER = "goldfinger"
+INCIDENCE = "incidence"
+SKETCHES = (GOLDFINGER, INCIDENCE)
+
 
 @dataclasses.dataclass(frozen=True)
 class GoldFinger:
-    """Fingerprints for a set of users: ``words`` uint32[n, W], ``card`` int32[n]."""
+    """Fingerprints for a set of users: ``words`` uint32[n, W], ``card``
+    int32[n]; ``sketch`` says how the bits were made (``SKETCHES``)."""
 
     words: np.ndarray | jax.Array  # uint32[n, W]
     card: np.ndarray | jax.Array   # int32[n]  (popcount of each row)
+    sketch: str = GOLDFINGER
 
     @property
     def n(self) -> int:
@@ -43,7 +55,8 @@ class GoldFinger:
         return self.words.shape[1] * 32
 
     def take(self, idx) -> "GoldFinger":
-        return GoldFinger(words=self.words[idx], card=self.card[idx])
+        return GoldFinger(words=self.words[idx], card=self.card[idx],
+                          sketch=self.sketch)
 
 
 def item_bit_positions(items: np.ndarray, n_bits: int, seed: int) -> np.ndarray:
@@ -66,6 +79,7 @@ def fingerprint_dataset(ds: Dataset, n_bits: int = DEFAULT_BITS, seed: int = 0) 
                             ds.profile_sizes)
         np.bitwise_or.at(words, (user_of, word_idx), bit)
         card = popcount_rows(words)
+        trace.add("repro.fingerprint.words", W)
     return GoldFinger(words=words, card=card)
 
 
@@ -74,22 +88,46 @@ def popcount_rows(words: np.ndarray) -> np.ndarray:
     return np.unpackbits(words.view(np.uint8), axis=-1).sum(axis=-1).astype(np.int32)
 
 
-def incidence_fingerprint(ds: Dataset) -> GoldFinger:
-    """Full-universe incidence vectors ("raw data" mode, Table V).
+def incidence_fingerprint(ds: Dataset, n_bits: int | None = None) -> GoldFinger:
+    """Full-universe incidence rows ("raw data" mode, Table V).
 
-    One bit per item of the universe — popcount Jaccard over these is the
-    *exact* set Jaccard (no hashing collisions), at |I|/n_bits times the
-    memory and compute of a GoldFinger sketch. This is the paper's
-    raw-data baseline expressed in the same kernel-friendly layout.
+    Item ``i`` sets bit ``i``: popcount Jaccard over these rows is the
+    *exact* set Jaccard (no hash collisions), at |I|/n_bits times the
+    memory and compute of a GoldFinger sketch. ``n_bits`` is the row width,
+    by default the universe rounded up to whole words; a query of an index
+    passes the index's. An item at or past ``n_bits`` sets no bit but still
+    counts, once, in ``card``, so a query's sims against the index's rows
+    stay the exact Jaccard; an incidence index refuses to store such a row.
     """
-    W = (ds.n_items + 31) // 32
-    words = np.zeros((ds.n_users, W), dtype=np.uint32)
-    user_of = np.repeat(np.arange(ds.n_users, dtype=np.int64),
-                        ds.profile_sizes)
-    pos = ds.items.astype(np.int64)
-    np.bitwise_or.at(words, (user_of, pos // 32),
-                     np.uint32(1) << (pos % 32).astype(np.uint32))
-    return GoldFinger(words=words, card=popcount_rows(words))
+    with trace.span("repro.fingerprint"):
+        if n_bits is None:
+            n_bits = 32 * ((ds.n_items + 31) // 32)
+        assert n_bits % 32 == 0, "n_bits must be a multiple of 32"
+        W = n_bits // 32
+        words = np.zeros((ds.n_users, W), dtype=np.uint32)
+        user_of = np.repeat(np.arange(ds.n_users, dtype=np.int64),
+                            ds.profile_sizes)
+        pos = ds.items.astype(np.int64)
+        inside = pos < n_bits
+        np.bitwise_or.at(words, (user_of[inside], pos[inside] // 32),
+                         np.uint32(1) << (pos[inside] % 32).astype(np.uint32))
+        card = popcount_rows(words)
+        if not inside.all():
+            past = np.unique(np.stack([user_of[~inside], pos[~inside]]),
+                             axis=1)
+            card += np.bincount(past[0], minlength=ds.n_users).astype(
+                np.int32)
+        trace.add("repro.fingerprint.words", W)
+    return GoldFinger(words=words, card=card, sketch=INCIDENCE)
+
+
+def sketch_dataset(ds: Dataset, params) -> GoldFinger:
+    """The rows a build scores, as ``params`` (a ``C2Params``) asks:
+    GoldFinger at ``params.n_bits`` bits and ``params.seed``, or, with
+    ``params.use_goldfinger`` False, incidence rows of the universe."""
+    if params.use_goldfinger:
+        return fingerprint_dataset(ds, n_bits=params.n_bits, seed=params.seed)
+    return incidence_fingerprint(ds)
 
 
 # --------------------------------------------------------------------------
@@ -138,7 +176,9 @@ def unpack_bits_int8(words: jax.Array) -> jax.Array:
     """uint32[n, W] → int8[n, W·32] {0,1} bit planes (LSB-first per word).
 
     This is the MXU path: ``popcount(a & b) == unpack(a) @ unpack(b).T``,
-    turning bit intersection into an int8 matmul (DESIGN.md §3).
+    turning bit intersection into an int8 matmul. ``_group_knn``
+    (core/local_knn.py) takes it for incidence rows; PERF.md §3 lists the
+    metrics that read it.
     """
     shifts = jnp.arange(32, dtype=jnp.uint32)
     bits = (words[..., :, None] >> shifts[None, None, :]) & jnp.uint32(1)

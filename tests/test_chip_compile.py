@@ -5,8 +5,10 @@ a ``v5e:2x2`` topology at the shapes ``chip_smoke.py`` runs: the
 MovieLens-10M index (69,816 users, padded by the serving plan to
 131,072 rows), 1024-bit GoldFinger sketches (W = 32 words), k = 30
 forward and reverse neighbors, a 256-query wave with beam 32, the
-build's 2048-row capacity groups, and the build's FRH distinct-hash
-table (t = 8, b = 4096, depth 6) over ml10M's 5.9 M items. A compile
+build's 2048-row capacity groups, the build's FRH distinct-hash
+table (t = 8, b = 4096, depth 6) over ml10M's 5.9 M items, and the
+raw-data build's group program on 10,496-bit incidence rows (W = 328
+words), which takes the int8 bit-plane matmul. A compile
 that passes here is not a chip run; what the chip's compiler refuses
 fails here for free.
 
@@ -32,6 +34,7 @@ BEAM = 32
 Q = 256               # one wave
 SEEDS = 128           # 8 hash configurations x 16 routed seeds
 CAP, M_GROUP = 2048, 16   # one capacity group of the build
+W_RAW = 328           # ml10M incidence rows: 10,472 items in 10,496 bits
 N_PROFILES, P_MAX = 70_144, 1348  # ml10M users padded to 256; widest profile
 N_USERS, NNZ = 69_816, 5_885_489  # ml10M users and their items, unpadded
 
@@ -145,3 +148,16 @@ def test_jnp_wave_program_compiles_at_smoke_shapes(one_chip):
         s((Q, W), jnp.uint32), s((Q,), jnp.int32), s((Q, SEEDS), jnp.int32),
         k=10, beam=BEAM, hops=3, tomb=s((N_ROWS,), jnp.bool_)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
+
+
+def test_group_knn_takes_the_mxu_branch_at_incidence_width(one_chip):
+    from repro.core.local_knn import _group_knn
+
+    compiled = _group_knn.lower(
+        _shape((M_GROUP, CAP, W_RAW), jnp.uint32, one_chip),
+        _shape((M_GROUP, CAP), jnp.int32, one_chip),
+        _shape((M_GROUP, CAP), jnp.int32, one_chip), k=KG).compile()
+    text = compiled.as_text()
+    # The intersection is an int8 product with int32 accumulation.
+    assert "convolution" in text and "s8[" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30

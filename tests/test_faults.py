@@ -188,7 +188,7 @@ def test_masked_seed_descent_parity(index, query_profiles):
     matches descend on a healthy fleet whose seeds were pre-filtered to
     drop the dead shard's owned basins."""
     items, offsets = profiles_to_csr(query_profiles)
-    qgf = fingerprint_profiles(items, offsets, index.n_bits, index.fp_seed)
+    qgf = fingerprint_profiles(items, offsets, index)
     seeds = route(index, items, offsets, 16)
     qw = np.asarray(qgf.words)
     qc = np.asarray(qgf.card)

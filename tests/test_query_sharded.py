@@ -36,7 +36,7 @@ def query_profiles():
 @pytest.fixture(scope="module")
 def exact(index, query_profiles):
     items, offsets = profiles_to_csr(query_profiles)
-    qgf = fingerprint_profiles(items, offsets, index.n_bits, index.fp_seed)
+    qgf = fingerprint_profiles(items, offsets, index)
     ids, _ = exact_knn(index.words, index.card, np.asarray(qgf.words),
                        np.asarray(qgf.card), 10)
     return ids
@@ -143,7 +143,7 @@ index = build_index(ds, C2Params(k=10, b=64, t=8, max_cluster=48))
 qds = make_dataset("synth", scale=0.1, seed=77)
 profiles = [qds.profile(u) for u in range(32)]
 items, offsets = profiles_to_csr(profiles)
-qgf = fingerprint_profiles(items, offsets, index.n_bits, index.fp_seed)
+qgf = fingerprint_profiles(items, offsets, index)
 seeds = route(index, items, offsets, 16)
 qn = len(profiles); qcap = capacity_of(qn, minimum=8)
 qw = np.zeros((qcap, np.asarray(qgf.words).shape[1]), np.uint32); qw[:qn] = qgf.words
